@@ -8,9 +8,11 @@ VPU.  No HBM traffic besides the [C, K_max, B] int32 output — the permutation
 is *computed*, not stored, so per-round memory stays O(cohort) regardless of
 population size.
 
-Per-slot scalars ride in SMEM; the flat [1, K*B] block layout follows the
-``server_update`` kernel's 1-D chunk idiom (row/column of a step are derived
-from the in-block iota, so no 2-D tiling constraints on small B).
+The per-slot scalars are scalar-prefetched into SMEM and read at the
+program's index.  The output is laid out [C, 1, K*B] so each program's
+``(1, K*B)`` row block spans the array's last two dimensions — which the TPU
+lowering accepts for any K*B (row/column of a step are derived from the
+in-block iota, so no tiling constraint on small B).
 """
 from __future__ import annotations
 
@@ -19,15 +21,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import fmix32, key_combine, swap_or_not
 
 
 def _rr_kernel(prekey_ref, n_ref, spe_ref, out_ref, *, B, K, rounds, mode):
     dt = jnp.uint32
-    key0 = prekey_ref[0]
-    n = n_ref[0].astype(dt)
-    spe = spe_ref[0]
+    i = pl.program_id(0)
+    key0 = prekey_ref[i]
+    n = n_ref[i].astype(dt)
+    spe = spe_ref[i]
     t = jax.lax.broadcasted_iota(jnp.int32, (1, K * B), 1)
     k = t // B                                         # local step
     e = k // spe                                       # epoch
@@ -47,14 +51,14 @@ def rr_indices_kernel(prekey, sizes, spe, *, B: int, K: int, rounds: int = 24,
     (C,) = prekey.shape
     out = pl.pallas_call(
         functools.partial(_rr_kernel, B=B, K=K, rounds=rounds, mode=mode),
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((1, K * B), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, K * B), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(C,),
+            in_specs=[],
+            out_specs=pl.BlockSpec((pl.Squeezed(), 1, K * B),
+                                   lambda i, *_: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((C, 1, K * B), jnp.int32),
         interpret=interpret,
     )(prekey, sizes, spe)
     return out.reshape(C, K, B)

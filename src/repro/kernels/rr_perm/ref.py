@@ -78,7 +78,9 @@ def swap_or_not(x, n, key, rounds: int, xp):
         kr_key = key_combine(key, dt(r), xp)
         kr = fmix32(kr_key, xp) % n                    # round key in [0, n)
         partner = (kr + n - x) % n                     # (K_r - x) mod n
-        canon = xp.maximum(x, partner)                 # same for both partners
+        # same for both partners; a compare-select, not xp.maximum — the TPU
+        # kernel compiler has no unsigned vector max
+        canon = xp.where(x > partner, x, partner)
         bit = key_combine(kr_key, canon, xp) & dt(1)
         x = xp.where(bit == dt(1), partner, x)
     return x
