@@ -58,15 +58,19 @@ def local_sgd(loss_fn: Callable, params, data, step_mask, lr):
     def step(y, xs):
         mb, m = xs
         (l, _), g = grad_fn(y, mb)
-        y = jax.tree.map(
-            lambda a, b: (a.astype(jnp.float32) - (lr * m) * b.astype(jnp.float32)).astype(a.dtype),
-            y, g,
-        )
+        with jax.named_scope("local_apply"):
+            y = jax.tree.map(
+                lambda a, b: (a.astype(jnp.float32) - (lr * m) * b.astype(jnp.float32)).astype(a.dtype),
+                y, g,
+            )
         return y, l * m
 
-    y, losses = jax.lax.scan(step, params, (data, step_mask))
+    with jax.named_scope("local_step"):
+        y, losses = jax.lax.scan(step, params, (data, step_mask))
     denom = jnp.maximum(step_mask.sum(), 1.0)
-    return tree_sub(y, params), losses.sum() / denom
+    with jax.named_scope("client_delta"):
+        delta = tree_sub(y, params)
+    return delta, losses.sum() / denom
 
 
 def local_mvr(loss_fn: Callable, params, momentum, data, step_mask, lr, a):
@@ -93,14 +97,18 @@ def local_mvr(loss_fn: Callable, params, momentum, data, step_mask, lr, a):
             * (ml.astype(jnp.float32) - gxl.astype(jnp.float32)),
             gy, gx, momentum,
         )
-        y = jax.tree.map(
-            lambda p, dl: (p.astype(jnp.float32) - (lr * m) * dl).astype(p.dtype), y, d
-        )
+        with jax.named_scope("local_apply"):
+            y = jax.tree.map(
+                lambda p, dl: (p.astype(jnp.float32) - (lr * m) * dl).astype(p.dtype), y, d
+            )
         return y, l * m
 
-    y, losses = jax.lax.scan(step, params, (data, step_mask))
+    with jax.named_scope("local_step"):
+        y, losses = jax.lax.scan(step, params, (data, step_mask))
     denom = jnp.maximum(step_mask.sum(), 1.0)
-    return tree_sub(y, params), losses.sum() / denom
+    with jax.named_scope("client_delta"):
+        delta = tree_sub(y, params)
+    return delta, losses.sum() / denom
 
 
 # ---------------------------------------------------------------------------
@@ -262,17 +270,20 @@ def build_local_step(transforms: tuple, loss_fn: Callable) -> Callable:
                 # a masked step must be an exact no-op for carry state too
                 new_carries.append(jax.tree.map(
                     lambda n, o: jnp.where(m > 0, n, o), c_new, c))
-            y = jax.tree.map(
-                lambda p, dl: (p.astype(jnp.float32) - (eta * m) * dl).astype(p.dtype),
-                y, d,
-            )
+            with jax.named_scope("local_apply"):
+                y = jax.tree.map(
+                    lambda p, dl: (p.astype(jnp.float32) - (eta * m) * dl).astype(p.dtype),
+                    y, d,
+                )
             return (y, tuple(new_carries)), l * m
 
         carries0 = tuple(t.init(params) for t in transforms)
-        (y, carries), losses = jax.lax.scan(step, (params, carries0),
-                                            (data, step_mask))
+        with jax.named_scope("local_step"):
+            (y, carries), losses = jax.lax.scan(step, (params, carries0),
+                                                (data, step_mask))
         denom = jnp.maximum(step_mask.sum(), 1.0)
-        delta = tree_sub(y, params)
+        with jax.named_scope("client_delta"):
+            delta = tree_sub(y, params)
         new_cstate = cstate
         shippers = tuple(t for t in transforms if t.finalize_delta is not None)
         end = None

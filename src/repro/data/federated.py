@@ -27,6 +27,7 @@ from typing import Any, NamedTuple
 import numpy as np
 
 from ..configs.base import FLConfig
+from ..obs import trace
 from .reshuffle import local_step_indices, steps_for
 from .tasks import HELDOUT_BASE
 
@@ -564,12 +565,14 @@ class FederatedPipeline:
     # -- batch materialization (the legacy / reference data path) ----------
 
     def round_batch(self, rnd: int) -> "RoundBatch | BucketedBatch":
-        plan = self.index_plan(rnd, with_idx=True)
-        if self.fl.exec_mode == "bucketed":
-            bplan = self.bucketize(plan)
-            if isinstance(bplan, BucketedPlan):
-                return self._materialize_bucketed(bplan)
-        return self._materialize_padded(plan)
+        with trace.span("data/index_plan", round=rnd):
+            plan = self.index_plan(rnd, with_idx=True)
+            if self.fl.exec_mode == "bucketed":
+                plan = self.bucketize(plan)     # the padded plan on overflow
+        with trace.span("data/materialize", round=rnd):
+            if isinstance(plan, BucketedPlan):
+                return self._materialize_bucketed(plan)
+            return self._materialize_padded(plan)
 
     def _materialize_padded(self, plan: IndexPlan) -> RoundBatch:
         C, K, B = self.cohort_slots, self.k_max, self.fl.local_batch

@@ -86,24 +86,25 @@ class DevicePlane:
 
     def materialize(self, plan: "IndexPlan | BucketedPlan") -> "RoundBatch | BucketedBatch":
         """Index plan -> round batch, inside the jitted round step."""
-        if isinstance(plan, BucketedPlan):
-            buckets = []
-            for b in plan.buckets:
-                cids = jnp.take(plan.meta.client_id, b.slots, axis=0)
-                idx = b.idx
-                if idx is None:
-                    idx = self._indices(cids,
-                                        jnp.take(plan.sizes, b.slots, axis=0),
-                                        jnp.take(plan.spe, b.slots, axis=0),
-                                        plan.rnd, int(b.step_mask.shape[1]))
-                data = self.gather(cids.astype(jnp.int32), idx)
-                buckets.append(Bucket(data=data, idx=None,
-                                      step_mask=b.step_mask, slots=b.slots))
-            return BucketedBatch(buckets=tuple(buckets), meta=plan.meta,
-                                 pos=plan.pos)
-        idx = plan.idx if plan.idx is not None else self.device_indices(plan)
-        data = self.gather(plan.meta.client_id.astype(jnp.int32), idx)
-        return RoundBatch(data=data, step_mask=plan.step_mask, meta=plan.meta)
+        with jax.named_scope("plan_materialize"):
+            if isinstance(plan, BucketedPlan):
+                buckets = []
+                for b in plan.buckets:
+                    cids = jnp.take(plan.meta.client_id, b.slots, axis=0)
+                    idx = b.idx
+                    if idx is None:
+                        idx = self._indices(cids,
+                                            jnp.take(plan.sizes, b.slots, axis=0),
+                                            jnp.take(plan.spe, b.slots, axis=0),
+                                            plan.rnd, int(b.step_mask.shape[1]))
+                    data = self.gather(cids.astype(jnp.int32), idx)
+                    buckets.append(Bucket(data=data, idx=None,
+                                          step_mask=b.step_mask, slots=b.slots))
+                return BucketedBatch(buckets=tuple(buckets), meta=plan.meta,
+                                     pos=plan.pos)
+            idx = plan.idx if plan.idx is not None else self.device_indices(plan)
+            data = self.gather(plan.meta.client_id.astype(jnp.int32), idx)
+            return RoundBatch(data=data, step_mask=plan.step_mask, meta=plan.meta)
 
 
 def _table_bank(task, population: Population):

@@ -86,7 +86,7 @@ import jax.numpy as jnp
 from ..configs.base import FLConfig
 from ..data.federated import Bucket, BucketedBatch, RoundBatch
 from ..obs import hist as obs_hist
-from ..obs import metrics_enabled
+from ..obs import metrics_enabled, trace
 from ..utils.pytree import tree_zeros_like
 from .bucketing import scan_clients, vmap_clients
 from .comm import (DOWNLINK_STATE_KEY, UPLINK_STATE_KEY, dense_bits,
@@ -187,7 +187,8 @@ def build_round_step(loss_fn: Callable,
         # before anything rebinds ``state`` (safe under donation: reads of
         # the donated buffers happen inside this jit, before release)
         prev_state = state if g_rej else None
-        plan = strat.client_transform(meta, lr_mult)                   # eta [C]
+        with jax.named_scope("client_transform"):
+            plan = strat.client_transform(meta, lr_mult)               # eta [C]
         momentum = state.opt.get("m", None)
         if momentum is None:
             momentum = tree_zeros_like(state.params)
@@ -203,10 +204,11 @@ def build_round_step(loss_fn: Callable,
             # gather the cohort's rows of the per-client state bank (invalid
             # padding slots read — and later write — the scratch row, so a
             # round's state traffic is O(cohort) regardless of population)
-            ids = jnp.where(meta.valid > 0, meta.client_id,
-                            num_clients).astype(jnp.int32)
-            cstate0 = jax.tree.map(lambda b: jnp.take(b, ids, axis=0),
-                                   state.clients)
+            with jax.named_scope("bank_gather"):
+                ids = jnp.where(meta.valid > 0, meta.client_id,
+                                num_clients).astype(jnp.int32)
+                cstate0 = jax.tree.map(lambda b: jnp.take(b, ids, axis=0),
+                                       state.clients)
         else:
             cstate0 = {}
 
@@ -221,13 +223,14 @@ def build_round_step(loss_fn: Callable,
         # writes must agree), not to a per-slot reconstruction.
         cstate_in = cstate0
         if dl_on:
-            if down.seeded:
-                dkeys = downlink_round_keys(fl.seed, meta.client_id,
-                                            state.rnd, jnp)
-            else:
-                dkeys = jnp.zeros(meta.valid.shape, jnp.uint32)
-            params_hat = jax.vmap(apply_down, in_axes=(None, 0, 0))(
-                state.params, cstate0[DOWNLINK_STATE_KEY]["ref"], dkeys)
+            with jax.named_scope("downlink"):
+                if down.seeded:
+                    dkeys = downlink_round_keys(fl.seed, meta.client_id,
+                                                state.rnd, jnp)
+                else:
+                    dkeys = jnp.zeros(meta.valid.shape, jnp.uint32)
+                params_hat = jax.vmap(apply_down, in_axes=(None, 0, 0))(
+                    state.params, cstate0[DOWNLINK_STATE_KEY]["ref"], dkeys)
             cstate_in = {**cstate0, DOWNLINK_STATE_KEY: {"ref": params_hat}}
 
         def client(data_i, mask_i, eta_i, cs_i):
@@ -259,8 +262,9 @@ def build_round_step(loss_fn: Callable,
         def secagg_agg(deltas, coeff):
             """Masked modular fixed-point aggregation (fed.privacy.secagg):
             pairwise masks cancel exactly, dropped clients' shares recovered."""
-            return secagg_combine(deltas, coeff, meta.valid, meta.dropped,
-                                  meta.client_id, state.rnd, fl)
+            with jax.named_scope("update_path"):
+                return secagg_combine(deltas, coeff, meta.valid, meta.dropped,
+                                      meta.client_id, state.rnd, fl)
 
         def robust_combine(deltas):
             """Aggregate the decoded slot-order stack under the robustness
@@ -304,37 +308,43 @@ def build_round_step(loss_fn: Callable,
             else:
                 deltas, losses, new_cs = jax.vmap(client)(
                     batch.data, batch.step_mask, plan.eta, cstate_in)
-            if dp_on:
-                # client-side DP clipping of the shipped update (the exact
-                # sensitivity bound) — before attacks: adversaries are not
-                # assumed to honor it (that is the robust plane's problem)
-                deltas, dp_clipped, dp_scale = dp_clip_cohort(deltas, fl)
-            if apply_attack is not None:
-                # before encode: adversaries control their wire payload
-                deltas = apply_attack(deltas, meta, state.rnd)
-            deltas, new_cs = uplink_cohort(deltas, new_cs)
+            with jax.named_scope("update_path"):
+                if dp_on:
+                    # client-side DP clipping of the shipped update (the exact
+                    # sensitivity bound) — before attacks: adversaries are not
+                    # assumed to honor it (that is the robust plane's problem)
+                    deltas, dp_clipped, dp_scale = dp_clip_cohort(deltas, fl)
+                if apply_attack is not None:
+                    # before encode: adversaries control their wire payload
+                    deltas = apply_attack(deltas, meta, state.rnd)
+                deltas, new_cs = uplink_cohort(deltas, new_cs)
             if tele_hist:
                 slot_sq = obs_hist.slot_sqnorms(deltas)
             if robust_on:
-                delta_agg, rb_info = robust_combine(deltas)
+                with jax.named_scope("update_path"):
+                    delta_agg, rb_info = robust_combine(deltas)
             elif sa_on:
                 delta_agg = secagg_agg(deltas, strat.agg_coeffs(meta))
             else:
-                delta_agg = strat.aggregate(deltas, meta)
+                with jax.named_scope("accumulate"):
+                    delta_agg = strat.aggregate(deltas, meta)
         else:  # sequential: the scan accumulates coeff_i * Delta_i as it goes,
             # so the strategy contributes through agg_coeffs rather than the
             # whole-cohort aggregate hook
-            coeff = strat.agg_coeffs(meta)                             # [C]
+            with jax.named_scope("agg_coeffs"):
+                coeff = strat.agg_coeffs(meta)                         # [C]
             acc_dt = jnp.dtype(fl.accum_dtype)
-            acc0 = jax.tree.map(lambda x: jnp.zeros_like(x, acc_dt), state.params)
+            with jax.named_scope("accumulate"):
+                acc0 = jax.tree.map(lambda x: jnp.zeros_like(x, acc_dt), state.params)
 
             def add_weighted(acc, delta, coeff_i):
                 # THE accumulation rule — one definition, shared by the fused
                 # and the staged paths (the bitwise contract between them)
-                return jax.tree.map(
-                    lambda A, D: (A + coeff_i * D.astype(jnp.float32)).astype(A.dtype),
-                    acc, delta,
-                )
+                with jax.named_scope("accumulate"):
+                    return jax.tree.map(
+                        lambda A, D: (A + coeff_i * D.astype(jnp.float32)).astype(A.dtype),
+                        acc, delta,
+                    )
 
             deltas = None
             if bucketed:
@@ -361,17 +371,19 @@ def build_round_step(loss_fn: Callable,
                     (batch.data, batch.step_mask, plan.eta, cstate_in))
 
             if deltas is not None:
-                if dp_on:
-                    # same client-side clip as the vmapped path (slot order)
-                    deltas, dp_clipped, dp_scale = dp_clip_cohort(deltas, fl)
-                if apply_attack is not None:
-                    deltas = apply_attack(deltas, meta, state.rnd)
-                deltas, new_cs = uplink_cohort(deltas, new_cs)
+                with jax.named_scope("update_path"):
+                    if dp_on:
+                        # same client-side clip as the vmapped path (slot order)
+                        deltas, dp_clipped, dp_scale = dp_clip_cohort(deltas, fl)
+                    if apply_attack is not None:
+                        deltas = apply_attack(deltas, meta, state.rnd)
+                    deltas, new_cs = uplink_cohort(deltas, new_cs)
                 if tele_hist:
                     slot_sq = obs_hist.slot_sqnorms(deltas)
 
                 if robust_on:
-                    delta_agg, rb_info = robust_combine(deltas)
+                    with jax.named_scope("update_path"):
+                        delta_agg, rb_info = robust_combine(deltas)
                 elif sa_on:
                     delta_agg = secagg_agg(deltas, coeff)
                 else:
@@ -399,14 +411,17 @@ def build_round_step(loss_fn: Callable,
                     losses, new_cs, slot_sq = ys
                 else:
                     losses, new_cs = ys
-            delta_agg = jax.tree.map(lambda a, p: a.astype(p.dtype), delta_agg, state.params)
+            with jax.named_scope("accumulate"):
+                delta_agg = jax.tree.map(lambda a, p: a.astype(p.dtype), delta_agg,
+                                         state.params)
 
         if dp_on:
             # counter-based per-(seed, round) Gaussian noise on the weighted
             # aggregate — identical wherever the round is produced (legacy /
             # engine / prefetch / resume), mode-independent by construction
-            delta_agg, dp_sigma = add_dp_noise(
-                delta_agg, strat.agg_coeffs(meta), meta.valid, fl, state.rnd)
+            with jax.named_scope("update_path"):
+                delta_agg, dp_sigma = add_dp_noise(
+                    delta_agg, strat.agg_coeffs(meta), meta.valid, fl, state.rnd)
 
         cstate = None
         new_clients = None
@@ -425,19 +440,21 @@ def build_round_step(loss_fn: Callable,
             # — the bucketed reassembly's zeros row never reaches the bank),
             # then every slot scatters back to its own bank row in slot order
             valid = meta.valid
-            upd = jax.tree.map(
-                lambda n, o: jnp.where(
-                    (valid > 0).reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
-                new_cs, cstate0)
-            cstate = CohortState(old=cstate0, new=upd)
-            new_clients = jax.tree.map(
-                lambda b, u: b.at[ids].set(u.astype(b.dtype)),
-                state.clients, upd)
+            with jax.named_scope("bank_scatter"):
+                upd = jax.tree.map(
+                    lambda n, o: jnp.where(
+                        (valid > 0).reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+                    new_cs, cstate0)
+                cstate = CohortState(old=cstate0, new=upd)
+                new_clients = jax.tree.map(
+                    lambda b, u: b.at[ids].set(u.astype(b.dtype)),
+                    state.clients, upd)
 
         ctx = RoundCtx(batch=batch, lr_mult=lr_mult, momentum=momentum,
                        cstate=cstate)
-        state = strat.server_update(state, delta_agg,
-                                    jnp.asarray(fl.server_lr, jnp.float32), ctx)
+        with jax.named_scope("server_update"):
+            state = strat.server_update(state, delta_agg,
+                                        jnp.asarray(fl.server_lr, jnp.float32), ctx)
         if new_clients is not None:
             # server opts construct ServerState(params=, opt=, rnd=) — the
             # driver owns the bank and re-attaches the scattered update
@@ -560,23 +577,34 @@ def as_device_meta(meta):
         for a in meta])
 
 
+def _device_nbytes(rb) -> int:
+    """Bytes ``as_device_batch`` hands the device for ``rb``: four per meta
+    scalar (float32 or int32 under the meta policy), every other array at
+    its canonical JAX dtype."""
+    meta = 4 * sum(a.size for a in rb.meta if a is not None)
+    return meta + sum(x.size * jax.dtypes.canonicalize_dtype(x.dtype).itemsize
+                      for x in jax.tree.leaves(rb._replace(meta=None)))
+
+
 def as_device_batch(rb):
-    """Host RoundBatch / BucketedBatch (numpy) -> jnp pytree, float32 meta."""
-    if isinstance(rb, BucketedBatch):
-        return BucketedBatch(
-            buckets=tuple(
-                Bucket(data=jax.tree.map(jnp.asarray, b.data), idx=None,
-                       step_mask=jnp.asarray(b.step_mask),
-                       slots=jnp.asarray(b.slots))
-                for b in rb.buckets),
+    """Host RoundBatch / BucketedBatch (numpy) -> jnp pytree, float32 meta,
+    inside a ``data/to_device`` span that carries :func:`_device_nbytes`."""
+    with trace.span("data/to_device", bytes=_device_nbytes(rb)):
+        if isinstance(rb, BucketedBatch):
+            return BucketedBatch(
+                buckets=tuple(
+                    Bucket(data=jax.tree.map(jnp.asarray, b.data), idx=None,
+                           step_mask=jnp.asarray(b.step_mask),
+                           slots=jnp.asarray(b.slots))
+                    for b in rb.buckets),
+                meta=as_device_meta(rb.meta),
+                pos=jnp.asarray(rb.pos),
+            )
+        return type(rb)(
+            data=jax.tree.map(jnp.asarray, rb.data),
+            step_mask=jnp.asarray(rb.step_mask),
             meta=as_device_meta(rb.meta),
-            pos=jnp.asarray(rb.pos),
         )
-    return type(rb)(
-        data=jax.tree.map(jnp.asarray, rb.data),
-        step_mask=jnp.asarray(rb.step_mask),
-        meta=as_device_meta(rb.meta),
-    )
 
 
 _DONATION_SUPPORTED: bool | None = None

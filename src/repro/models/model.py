@@ -163,7 +163,8 @@ class Model:
         if cfg.family == "audio":
             return self._loss_encdec(params, batch, inputs, labels)
 
-        h = params["embed"][inputs]
+        with jax.named_scope("embed"):
+            h = params["embed"][inputs]
         offset = 0
         if cfg.family == "vlm":
             patches = batch["patches"].astype(h.dtype) @ params["patch_proj"]
@@ -173,23 +174,27 @@ class Model:
         if cfg.rope_kind == "none" and cfg.family not in ("ssm",):
             h = h + sinusoid(positions, cfg.d_model)[None].astype(h.dtype)
 
-        h, aux, _ = self._backbone(params, h, positions, window=cfg.sliding_window)
-        h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-        h_text = h[:, offset:]
-        logits = self._logits(params, h_text)
-        ce = softmax_xent(logits, labels, onehot=cfg.opt_onehot_xent).mean()
+        with jax.named_scope("blocks"):
+            h, aux, _ = self._backbone(params, h, positions, window=cfg.sliding_window)
+        with jax.named_scope("lm_head"):
+            h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+            h_text = h[:, offset:]
+            logits = self._logits(params, h_text)
+            ce = softmax_xent(logits, labels, onehot=cfg.opt_onehot_xent).mean()
         loss = ce + aux
         metrics = {"ce": ce, "aux": aux}
 
         if cfg.mtp and S >= 2:
             # multi-token prediction: combine h_t with emb(x_{t+1}) -> predict x_{t+2}
-            nxt = params["embed"][inputs[:, 1:]]
-            comb = jnp.concatenate([h_text[:, :-1], nxt], axis=-1) @ params["mtp_proj"]
-            pos2 = jnp.arange(S - 1)
-            fwd = _FWD[cfg.family]
-            hm, mtp_aux, _ = fwd(params["mtp_block"], cfg, comb, pos2, keep_cache=False)
-            mtp_logits = self._logits(params, rmsnorm(params["final_norm"], hm, cfg.norm_eps))
-            mtp_ce = softmax_xent(mtp_logits, labels[:, 1:], onehot=cfg.opt_onehot_xent).mean()
+            with jax.named_scope("lm_head"):
+                nxt = params["embed"][inputs[:, 1:]]
+                comb = jnp.concatenate([h_text[:, :-1], nxt], axis=-1) @ params["mtp_proj"]
+                pos2 = jnp.arange(S - 1)
+                fwd = _FWD[cfg.family]
+                hm, mtp_aux, _ = fwd(params["mtp_block"], cfg, comb, pos2, keep_cache=False)
+                mtp_logits = self._logits(params, rmsnorm(params["final_norm"], hm, cfg.norm_eps))
+                mtp_ce = softmax_xent(mtp_logits, labels[:, 1:],
+                                      onehot=cfg.opt_onehot_xent).mean()
             loss = loss + cfg.mtp_coef * (mtp_ce + mtp_aux)
             metrics["mtp_ce"] = mtp_ce
         return loss, metrics

@@ -12,17 +12,21 @@ cohort-prefetch producer).  A :class:`Tracer` records each phase as a *span*
 * a JSONL event log (``write_jsonl``) — one event per line for ad-hoc
   analysis without a trace viewer.
 
-Instrumentation sites call the *module-level* :func:`span` / :func:`counter`
-/ :func:`instant`, which no-op (one global read, shared null context) unless
-a tracer is active — so the train loop and the prefetch thread are always
-instrumented and tracing costs nothing until someone turns it on:
+Instrumentation sites call the *module-level* :func:`span` /
+:func:`counter`, which no-op (one global read, one profiler probe, shared
+null context) unless a tracer is active or a ``jax.profiler`` trace is
+recording — so the train loop, the data plane and the prefetch thread are
+always instrumented and tracing costs nothing until someone turns it on:
 
     with obs.trace.capture(chrome="trace.json", jsonl="events.jsonl"):
         train(loss, params, pipeline, fl, rounds=100)
 
-Spans are cheap (two ``perf_counter_ns`` calls + one list append), but they
-are host-side wall-clock only: device-side timing stays in the benchmarks.
-Span taxonomy (the names the built-in instrumentation emits):
+Spans are cheap (two ``perf_counter_ns`` calls + one list append).  While a
+``jax.profiler.trace`` records, every span also enters a
+``jax.profiler.TraceAnnotation`` of its name with its args as stats, so it
+lands on the profile's host plane on the device trace's clock, beside the
+device ops it dispatched.  Span taxonomy (the names the built-in
+instrumentation emits):
 
 ========================== ================================================
 ``round/plan_wait``        consumer blocked on the next round's plan
@@ -34,8 +38,13 @@ Span taxonomy (the names the built-in instrumentation emits):
 ``prefetch/plan_build``    producer-side plan production (both above)
 ``prefetch/backpressure``  producer blocked on the bounded queue
 ``prefetch/queue_depth``   counter: plans ready ahead of the consumer
+``data/index_plan``        legacy pipeline: cohort draw, reshuffle, padding
+``data/materialize``       legacy pipeline: the host token fill
+``data/to_device``         ``as_device_batch``; ``bytes`` = bytes handed over
 ``jax/backend_compile``    XLA compile observed by the sentinel listener
 ========================== ================================================
+
+Counters and the sentinel's compile spans go to the tracer only.
 """
 from __future__ import annotations
 
@@ -46,28 +55,37 @@ import time
 from contextlib import contextmanager
 from typing import Any, Iterator
 
+from jax.profiler import TraceAnnotation
+
 # ---------------------------------------------------------------------------
 # Tracer
 # ---------------------------------------------------------------------------
 
 
 class _Span:
-    """One live span (context manager); records itself on exit."""
+    """One live span (context manager); records itself on exit, inside
+    ``annotation`` (a profiler ``TraceAnnotation``) when one is given."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, args: dict,
+                 annotation: TraceAnnotation | None = None):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._annotation = annotation
 
     def __enter__(self) -> "_Span":
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
         self._tracer._add("X", self._name, self._t0, t1 - self._t0, self._args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class _NullSpan:
@@ -109,9 +127,6 @@ class Tracer:
     def span(self, name: str, **args: Any) -> _Span:
         return _Span(self, name, args)
 
-    def instant(self, name: str, **args: Any) -> None:
-        self._add("i", name, time.perf_counter_ns(), 0, args)
-
     def counter(self, name: str, **values: Any) -> None:
         self._add("C", name, time.perf_counter_ns(), 0, values)
 
@@ -130,7 +145,7 @@ class Tracer:
     # -- export -------------------------------------------------------------
 
     def chrome_events(self) -> list[dict]:
-        """Chrome ``trace_event`` array: thread metadata + X/C/i events."""
+        """Chrome ``trace_event`` array: thread metadata + X/C events."""
         pid = os.getpid()
         tids: dict[int, tuple[int, str]] = {}
         out: list[dict] = [{"ph": "M", "name": "process_name", "pid": pid,
@@ -145,8 +160,6 @@ class Tracer:
                   "ts": t_ns / 1e3}
             if ph == "X":
                 ev["dur"] = dur_ns / 1e3
-            elif ph == "i":
-                ev["s"] = "t"
             if args:
                 ev["args"] = dict(args)
             body.append(ev)
@@ -194,15 +207,13 @@ def stop() -> Tracer | None:
 
 
 def span(name: str, **args: Any):
-    """A span on the active tracer — the shared no-op when tracing is off."""
+    """A span on the active tracer and, while a ``jax.profiler`` trace
+    records, on the profile's host plane — the shared no-op when neither."""
     t = _ACTIVE
+    if TraceAnnotation.is_enabled():
+        annotation = TraceAnnotation(name, **args)
+        return annotation if t is None else _Span(t, name, args, annotation)
     return t.span(name, **args) if t is not None else _NULL_SPAN
-
-
-def instant(name: str, **args: Any) -> None:
-    t = _ACTIVE
-    if t is not None:
-        t.instant(name, **args)
 
 
 def counter(name: str, **values: Any) -> None:

@@ -1,7 +1,9 @@
 """Observability plane unit tests: sinks, instruments, histograms, tracer,
 recompile sentinels, log levels, and the MetricLogger CSV union fix.
 """
+import contextlib
 import json
+import re
 import threading
 
 import jax
@@ -173,7 +175,6 @@ def test_tracer_spans_threads_and_chrome_export(tmp_path):
         with trace.span("round/step_dispatch", round=0):
             pass
         trace.counter("prefetch/queue_depth", depth=2)
-        trace.instant("marker")
 
         def worker():
             with trace.span("prefetch/plan_build", round=1):
@@ -183,7 +184,7 @@ def test_tracer_spans_threads_and_chrome_export(tmp_path):
         t.start()
         t.join()
     assert trace.active() is None
-    assert len(tr) == 4
+    assert len(tr) == 3
     doc = json.load(open(tmp_path / "t.json"))
     evs = doc["traceEvents"]
     xs = [e for e in evs if e["ph"] == "X"]
@@ -196,7 +197,7 @@ def test_tracer_spans_threads_and_chrome_export(tmp_path):
     tids = {e["tid"] for e in xs}
     assert len(tids) == 2 and all(t < 16 for t in tids)
     lines = [json.loads(line) for line in open(tmp_path / "t.jsonl")]
-    assert len(lines) == 4 and lines[0]["thread"]
+    assert len(lines) == 3 and lines[0]["thread"]
 
 
 def test_capture_is_reentrant():
@@ -210,6 +211,124 @@ def test_capture_is_reentrant():
         with trace.span("outer2"):
             pass
     assert len(inner) == 1 and len(outer) == 2
+
+
+def _host_events(log_dir):
+    """(name, stats) of every host-plane event in the profile under log_dir."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+    return [(e.name, dict(e.stats)) for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+
+
+@pytest.mark.parametrize("with_tracer", [False, True])
+def test_span_lands_on_profiler_host_plane(tmp_path, with_tracer):
+    with trace.capture() if with_tracer else contextlib.nullcontext() as tr:
+        with jax.profiler.trace(str(tmp_path)):
+            with trace.span("data/to_device", bytes=65904):
+                jnp.ones(3).block_until_ready()
+    assert ("data/to_device", {"bytes": 65904}) in _host_events(str(tmp_path))
+    if with_tracer:
+        assert [(e["name"], e["args"]) for e in tr.events] == [
+            ("data/to_device", {"bytes": 65904})]
+    # neither a tracer nor a profile: the shared null span again
+    assert trace.span("data/to_device", bytes=1) is trace._NULL_SPAN
+
+
+def test_data_plane_spans_carry_round_and_bytes():
+    from repro.configs.base import FLConfig
+    from repro.data.federated import FederatedPipeline, Population
+    from repro.data.tasks import TokenTask
+    from repro.fed.rounds import as_device_batch
+
+    fl = FLConfig(num_clients=6, cohort_size=3, sampling="uniform", local_batch=2,
+                  imbalance="lognormal", mean_samples=6, seed=1)
+    pipe = FederatedPipeline(TokenTask(vocab=64, seq_len=8, num_clients=6),
+                             Population.build(fl), fl)
+    with trace.capture() as tr:
+        batch = as_device_batch(pipe.round_batch(5))
+    spans = [(e["name"], e["args"]) for e in tr.events]
+    # tokens int32 [C, K_max, B, T+1], mask float32 [C, K_max], and every
+    # meta field as a 4-byte [C] scalar
+    C, K = 3, pipe.k_max
+    meta = sum(a is not None for a in batch.meta)
+    nbytes = 4 * (C * K * 2 * 9 + C * K + meta * C)
+    assert spans == [("data/index_plan", {"round": 5}), ("data/materialize", {"round": 5}),
+                     ("data/to_device", {"bytes": nbytes})]
+    assert nbytes == sum(x.nbytes for x in jax.tree.leaves(batch))
+
+
+# The round step's named scopes (``jax.named_scope``): op metadata only.
+
+def _round_step_hlo(mode):
+    """Compiled HLO text of a tiny dense model's legacy round step."""
+    from repro.configs.base import FLConfig
+    from repro.configs.paper_tasks import CHARLM_TINY
+    from repro.data.federated import FederatedPipeline, Population
+    from repro.data.tasks import CharLMTask
+    from repro.fed.losses import make_loss
+    from repro.fed.rounds import as_device_batch, build_round_step
+    from repro.fed.strategy import bind_strategy
+    from repro.models.model import build_model
+
+    fl = FLConfig(num_clients=4, cohort_size=2, sampling="uniform", local_batch=2,
+                  imbalance="lognormal", mean_samples=4, cohort_mode=mode, seed=0)
+    pipe = FederatedPipeline(CharLMTask(vocab=CHARLM_TINY.vocab, seq_len=16, num_clients=4),
+                             Population.build(fl), fl)
+    model = build_model(CHARLM_TINY)
+    loss = make_loss(model)
+    strat = bind_strategy(None, fl, loss, num_clients=fl.num_clients)
+    step = jax.jit(build_round_step(loss, strat, fl))
+    state = strat.init(model.init(jax.random.PRNGKey(0)))
+    return step.lower(state, as_device_batch(pipe.round_batch(0))).compile().as_text()
+
+
+_HLO: dict = {}
+
+
+def _scoped_hlo(mode):
+    if mode not in _HLO:
+        _HLO[mode] = _round_step_hlo(mode)
+    return _HLO[mode]
+
+
+def _scope_names(hlo):
+    """Every name on the ops' ``op_name`` paths (``transpose(jvp(lm_head))``
+    gives ``transpose``, ``jvp``, ``lm_head``)."""
+    return {tok for path in re.findall(r'op_name="([^"]*)"', hlo)
+            for tok in re.findall(r"[\w.-]+", path)}
+
+
+@pytest.mark.parametrize("mode", ["sequential", "vmapped"])
+def test_round_step_hlo_names_its_layers(mode):
+    scopes = _scope_names(_scoped_hlo(mode))
+    want = {"local_step", "lm_head", "local_apply", "embed", "blocks",
+            "client_delta", "client_transform", "server_update"}
+    if mode == "sequential":
+        want |= {"accumulate", "agg_coeffs"}
+    assert want <= scopes, want - scopes
+
+
+@pytest.mark.parametrize("mode", ["sequential", "vmapped"])
+def test_named_scopes_add_no_op(mode, monkeypatch):
+    def strip(text):
+        # op metadata, and the stack-frame tables it points into (they hold
+        # the caller's frames too)
+        text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(.+\n)*", "",
+                      text, flags=re.M)
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+    scoped = _scoped_hlo(mode)
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = _round_step_hlo(mode)
+    assert "local_step" in _scope_names(scoped) and "local_step" not in _scope_names(plain)
+    assert "FileNames" not in strip(scoped) and " fusion(" in strip(scoped)
+    assert strip(scoped) == strip(plain)
 
 
 # ---------------------------------------------------------------------------
