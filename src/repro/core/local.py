@@ -3,7 +3,9 @@
 The non-identical-local-steps regime (different |D_i|, E_i) is carried by a
 static ``lax.scan`` over ``K_max`` steps with a per-step {0,1} mask — a masked
 step is an exact no-op, so the semantics match the paper's variable-length
-loops while shapes stay static for XLA.
+loops while shapes stay static for XLA.  Every producer of the mask pads at
+the end, so a client run alone stops at its last unmasked step instead
+(:func:`scan_to_last_step`, a traced trip count over the same shapes).
 
 Step-size convention (Algorithm 4): client i uses ``eta_l / c_i`` per local
 step, where the algorithm chooses ``c_i`` (FedShuffle: c_i = K_i, the number
@@ -241,7 +243,38 @@ def chain_client_template(transforms: tuple) -> Callable | None:
     return template
 
 
-def build_local_step(transforms: tuple, loss_fn: Callable) -> Callable:
+def scan_to_last_step(step: Callable, carry, data, step_mask):
+    """``lax.scan(step, carry, (data, step_mask))`` for a ``step`` that is an
+    exact no-op on a masked step, run only to the last unmasked one.
+
+    The trip count is traced: 1 + the last index with mask > 0, or 0 when
+    every step is masked, so one compiled loop serves every mask.  Each step
+    reads microbatch ``k`` by dynamic index, as the scan does, and writes its
+    output into a zero-initialised ``[K_max]`` buffer, so the skipped tail
+    reads 0 where the scan would have written ``l * 0``.  Masked steps before
+    the last unmasked one still run, and stay no-ops through ``step``'s own
+    masking.
+    """
+    k_max = step_mask.shape[0]
+    n = jnp.max(jnp.where(step_mask > 0, jnp.arange(1, k_max + 1), 0))
+    ys0 = jnp.zeros((k_max,), jnp.promote_types(step_mask.dtype, jnp.float32))
+
+    def body(k, state):
+        carry, ys = state
+        mb = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, k, keepdims=False),
+                          data)
+        carry, y = step(carry, (mb, step_mask[k]))
+        return carry, ys.at[k].set(y.astype(ys.dtype))
+
+    # the jit gives the loop a function of its own: a while loop's constants
+    # are hoisted to the top of the enclosing function and carry its scopes,
+    # which would take the loop body's ops out of the caller's named scope
+    # (``local_step``) on the profile; the compiled ops are the same
+    return jax.jit(lambda n, init: jax.lax.fori_loop(0, n, body, init))(n, (carry, ys0))
+
+
+def build_local_step(transforms: tuple, loss_fn: Callable, *,
+                     trim_padding: bool = False) -> Callable:
     """Compile a resolved transform chain into the per-client local update
 
         one_client(params, momentum, opt, data, step_mask, eta, cstate)
@@ -251,6 +284,12 @@ def build_local_step(transforms: tuple, loss_fn: Callable) -> Callable:
     the ``mvr`` transform, to :func:`local_mvr` (the equivalence suites hold
     both).  ``cstate`` maps stateful-transform names to that client's
     persistent slice (pass ``{}`` for stateless chains).
+
+    ``trim_padding`` runs the local steps through :func:`scan_to_last_step`,
+    so a client's tail of masked steps is never computed; the results are
+    bitwise those of the fixed ``K_max``-step scan.  It is for clients run
+    one at a time: under ``vmap`` a data-dependent loop runs to the cohort's
+    longest client and adds selects, so vmapped cohorts keep the scan.
     """
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
     stateful = tuple(t for t in transforms if t.client_init is not None)
@@ -279,8 +318,12 @@ def build_local_step(transforms: tuple, loss_fn: Callable) -> Callable:
 
         carries0 = tuple(t.init(params) for t in transforms)
         with jax.named_scope("local_step"):
-            (y, carries), losses = jax.lax.scan(step, (params, carries0),
-                                                (data, step_mask))
+            if trim_padding:
+                (y, carries), losses = scan_to_last_step(
+                    step, (params, carries0), data, step_mask)
+            else:
+                (y, carries), losses = jax.lax.scan(step, (params, carries0),
+                                                    (data, step_mask))
         denom = jnp.maximum(step_mask.sum(), 1.0)
         with jax.named_scope("client_delta"):
             delta = tree_sub(y, params)

@@ -14,7 +14,9 @@ strategy hooks (``repro.fed.strategy``).  Two cohort execution modes:
   model replica occupies one model-parallel slice.  Cross-device FL layout.
 * ``sequential`` — ``lax.scan`` over the cohort; each client uses the whole
   mesh (params FSDP+TP sharded) and the weighted delta is accumulated.
-  Cross-silo / huge-model layout (deepseek-v3 class).
+  Cross-silo / huge-model layout (deepseek-v3 class).  A client run alone
+  computes its local steps only up to its last unmasked one (the local
+  loop's trip count is traced; ``core.local.scan_to_last_step``).
 
 Both modes compute *identical* math:
     Delta = sum_i coeff_i * (y_i - x),   coeff_i = valid_i * w~_i / q_i^S
@@ -82,6 +84,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import FLConfig
 from ..data.federated import Bucket, BucketedBatch, RoundBatch
@@ -586,9 +589,27 @@ def _device_nbytes(rb) -> int:
                       for x in jax.tree.leaves(rb._replace(meta=None)))
 
 
+def local_steps(rb) -> tuple[int, int]:
+    """(laid out, computed) local steps of a host RoundBatch / BucketedBatch:
+    every slot of its ``[C, K]`` step masks, and what a sequential cohort
+    computes of them, each client up to its last unmasked step."""
+    masks = ([b.step_mask for b in rb.buckets] if isinstance(rb, BucketedBatch)
+             else [rb.step_mask])
+    computed = sum(
+        int(np.max(np.where(m > 0, np.arange(1, m.shape[1] + 1), 0),
+                   axis=1, initial=0).sum())
+        for m in masks)
+    return sum(m.size for m in masks), computed
+
+
 def as_device_batch(rb):
     """Host RoundBatch / BucketedBatch (numpy) -> jnp pytree, float32 meta,
-    inside a ``data/to_device`` span that carries :func:`_device_nbytes`."""
+    inside a ``data/to_device`` span that carries :func:`_device_nbytes`;
+    with a tracer active, a ``data/local_steps`` counter carries
+    :func:`local_steps`."""
+    if trace.active() is not None:
+        laid_out, computed = local_steps(rb)
+        trace.counter("data/local_steps", laid_out=laid_out, computed=computed)
     with trace.span("data/to_device", bytes=_device_nbytes(rb)):
         if isinstance(rb, BucketedBatch):
             return BucketedBatch(

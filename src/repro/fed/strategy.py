@@ -148,7 +148,10 @@ def _compile_local(entry: "ClientChain | Callable", loss_fn: Callable, fl: FLCon
         needs = tuple(dict.fromkeys(k for t in transforms for k in t.needs))
         state_names = tuple(t.name for t in transforms
                             if t.client_init is not None)
-        return (build_local_step(transforms, loss_fn),
+        # clients run one at a time stop at their last unmasked step;
+        # vmapped cohorts keep the fixed-length scan (build_local_step)
+        trim = fl.cohort_mode == "sequential"
+        return (build_local_step(transforms, loss_fn, trim_padding=trim),
                 chain_client_template(transforms), needs, state_names,
                 tuple(t.name for t in transforms))
     inner = entry(loss_fn, fl)  # legacy raw rule: stateless, opt-blind

@@ -41,6 +41,8 @@ instrumentation emits):
 ``data/index_plan``        legacy pipeline: cohort draw, reshuffle, padding
 ``data/materialize``       legacy pipeline: the host token fill
 ``data/to_device``         ``as_device_batch``; ``bytes`` = bytes handed over
+``data/local_steps``       counter: ``laid_out`` [C, K] mask slots and the
+                           steps a sequential cohort ``computed`` of them
 ``jax/backend_compile``    XLA compile observed by the sentinel listener
 ========================== ================================================
 

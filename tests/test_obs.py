@@ -258,7 +258,11 @@ def test_data_plane_spans_carry_round_and_bytes():
     C, K = 3, pipe.k_max
     meta = sum(a is not None for a in batch.meta)
     nbytes = 4 * (C * K * 2 * 9 + C * K + meta * C)
+    # a sequential cohort computes each client's steps up to its last unmasked one
+    computed = sum(max((k + 1 for k in range(K) if row[k] > 0), default=0)
+                   for row in np.asarray(batch.step_mask))
     assert spans == [("data/index_plan", {"round": 5}), ("data/materialize", {"round": 5}),
+                     ("data/local_steps", {"laid_out": C * K, "computed": computed}),
                      ("data/to_device", {"bytes": nbytes})]
     assert nbytes == sum(x.nbytes for x in jax.tree.leaves(batch))
 
@@ -312,6 +316,35 @@ def test_round_step_hlo_names_its_layers(mode):
     if mode == "sequential":
         want |= {"accumulate", "agg_coeffs"}
     assert want <= scopes, want - scopes
+
+
+def _computations(hlo):
+    """HLO text -> {computation name: its instruction lines}."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{$", line)
+        if m:
+            name, out[m.group(1)] = m.group(1), []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["sequential", "vmapped"])
+def test_local_loop_body_ops_carry_local_step(mode):
+    """Every op of the local loop's body names ``local_step``: a profile
+    reduction gives an op the compiler made without metadata the scopes its
+    computation's ops share, so one stray op there drops the whole step's
+    fusions out of the scope."""
+    hlo = _scoped_hlo(mode)
+    comps = _computations(hlo)
+    bodies = [comps[b] for b in set(re.findall(r"body=%?([\w.-]+)", hlo))]
+    paths = [re.findall(r'op_name="([^"]*)"', "\n".join(b)) for b in bodies]
+    local = [p for p in paths if any("local_apply" in n for n in p)]
+    assert local
+    assert [n for p in local for n in p if "local_step" not in n] == []
 
 
 @pytest.mark.parametrize("mode", ["sequential", "vmapped"])
