@@ -2,16 +2,22 @@
 
 The window drives what ``repro.fed.train_loop.train`` builds for the legacy
 engine: ``build_round_step`` + ``jit_round_step`` over the bound strategy,
-fed by ``FederatedPipeline.round_batch`` -> ``as_device_batch``, each round
-ending in the host fetch of its loss.  Set-up makes the weights from the
-seed, builds that step and state once, and drives the first rounds through
-the same call; those rounds are compared with the plain reference after the
-window has closed.
+fed by ``FederatedPipeline.round_batch`` -> ``as_device_batch``, and fetches
+every round's loss to the host.  It keeps up to ``AHEAD_S`` seconds of rounds
+dispatched ahead of the loss it waits for, so that the chip stays fed while
+the host stands still (the device runtime may hold the host back sooner, in
+``as_device_batch``); when its time is up it sends nothing more, waits for
+every round it sent, and only then reads the clock.  Set-up makes the weights
+from the seed, builds that step and state once, and drives the first rounds
+through the same call, one round at a time; those rounds are compared with the
+plain reference after the window has closed.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import gc
+import json
 import math
 import shutil
 import statistics
@@ -22,8 +28,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, FLConfig
+from repro.configs.base import FLConfig
 from repro.data.federated import FederatedPipeline, Population
+from repro.fed import rounds as fed_rounds
 from repro.fed.losses import make_loss
 from repro.fed.rounds import as_device_batch, build_round_step, jit_round_step
 from repro.fed.strategy import bind_strategy
@@ -31,9 +38,11 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 from repro.obs import sentinels
 
-from . import clients, compare, reference, spec, trace_reduce
+from . import clients, compare, reference, scopes, spec, trace_reduce
 from .flops import train_flops_per_token
 from .weights import change_norms, init_params
+
+AHEAD_S = 5.0   # device seconds of rounds queued behind the loss the window waits for
 
 
 def annotate(name: str):
@@ -41,13 +50,17 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(trace_reduce.HOST_PREFIX + name)
 
 
-def arch_config(s: spec.ModelShape, name: str) -> ArchConfig:
-    return ArchConfig(
-        name=name, family="dense", n_layers=s.layers, d_model=s.d_model,
-        n_heads=s.heads, n_kv_heads=s.kv_heads,
-        head_dim=0 if s.heads * s.head_dim == s.d_model else s.head_dim,
-        d_ff=s.d_ff, vocab=s.vocab, qkv_bias=s.qkv_bias, rope_theta=s.rope_theta,
-        tie_embeddings=s.tied, norm_eps=s.norm_eps, dtype=s.dtype)
+def arch_config(s, name: str):
+    """The program's ``ArchConfig`` of the family shape ``s``."""
+    return spec.family_of(s).arch_config(s, name)
+
+
+def computed_steps(rb) -> int:
+    """Local steps the program computes of a round's batch: what
+    ``repro.fed.rounds.local_steps`` says, or every slot of the ``[C, K_max]``
+    layout where the program has no such function."""
+    local_steps = getattr(fed_rounds, "local_steps", None)
+    return local_steps(rb)[1] if local_steps is not None else int(rb.step_mask.size)
 
 
 @dataclass
@@ -55,8 +68,10 @@ class RoundRecord:
     rnd: int
     loss: float
     useful_steps: int     # unmasked local steps over the cohort
-    padded_steps: int     # C * K_max steps the padded scan computes
-    plan_s: float         # host seconds in round_batch + as_device_batch
+    padded_steps: int     # C * K_max steps the cohort layout holds
+    computed_steps: int   # of those, the steps the program computed
+    plan_s: float         # host seconds in round_batch
+    transfer_s: float     # host seconds in as_device_batch, any wait for the runtime's queue with them
     dispatch_s: float     # host seconds in the step's call
     fetch_s: float        # host seconds waiting for the round's loss
     inputs: tuple         # (client ids [C], step mask [C, K], tokens [C, K, B, T+1])
@@ -78,6 +93,7 @@ class Program:
             self.fl, sizes=clients.client_sizes(cell.traffic["clients"], self.fl.num_clients))
         self.lr_mult = jnp.float32(1.0)
         self.pipe = self.state = self.batch = None
+        self.warm: list = []
 
     def start(self, seed: int) -> None:
         task = clients.TokenRows(self.shape.vocab, self.cell.traffic["seq_len"], seed)
@@ -85,39 +101,68 @@ class Program:
         # the strategy's init copies the weights; the rounds donate the copy
         self.state = self.strat.init(init_params(self.shape, seed))
 
-    def round(self, r: int) -> RoundRecord:
+    def send(self, r: int) -> tuple[RoundRecord, dict]:
+        """Round ``r``'s batch and step, dispatched; its loss not yet read."""
         t0 = time.perf_counter()
         with annotate("round_batch"):
             rb = self.pipe.round_batch(r)
+        t1 = time.perf_counter()
         with annotate("as_device_batch"):
             batch = as_device_batch(rb)
-        t1 = time.perf_counter()
+        t2 = time.perf_counter()
         with annotate("dispatch"):
             self.state, mets = self.step(self.state, batch, self.lr_mult)
-        t2 = time.perf_counter()
-        with annotate("metrics_fetch"):
-            loss = float(mets["local_loss"])
+        t3 = time.perf_counter()
         self.batch = batch
         mask = rb.step_mask
-        return RoundRecord(r, loss, int(mask.sum()), int(mask.size), t1 - t0, t2 - t1,
-                           time.perf_counter() - t2, (rb.meta.client_id, mask, rb.data["tokens"]))
+        return RoundRecord(r, math.nan, int(mask.sum()), int(mask.size), computed_steps(rb),
+                           t1 - t0, t2 - t1, t3 - t2, 0.0,
+                           (rb.meta.client_id, mask, rb.data["tokens"])), mets
+
+    @staticmethod
+    def fetch(rec: RoundRecord, mets: dict) -> RoundRecord:
+        """Wait for a sent round's loss and read it to the host."""
+        t0 = time.perf_counter()
+        with annotate("metrics_fetch"):
+            rec.loss = float(mets["local_loss"])
+        rec.fetch_s = time.perf_counter() - t0
+        return rec
+
+    def round(self, r: int) -> RoundRecord:
+        return self.fetch(*self.send(r))
 
     def warm_up(self, seed: int, rounds: int) -> reference.Readings:
-        """Rounds 0..rounds-1 through the window's own call, with what the
-        comparison reads of them."""
+        """Rounds 0..rounds-1 through the window's own call, one at a time,
+        with what the comparison reads of them."""
         records, grad_norms = [], None
         for r in range(rounds):
             records.append(self.round(r))
             if r == 0:
                 grad_norms = change_norms(self.shape, self.state.params, seed)
+        self.warm = records
         return reference.Readings(
             [x.loss for x in records], grad_norms,
             change_norms(self.shape, self.state.params, seed),
             [x.inputs for x in records])
 
-    def step_memory(self) -> dict:
-        """The compiler's memory analysis of the round step the window ran."""
-        ma = self.step.lower(self.state, self.batch, self.lr_mult).compile().memory_analysis()
+    def ahead_steps(self) -> int:
+        """Computed steps worth ``AHEAD_S`` device seconds, by the fastest
+        warm-up round after the first (which compiles or loads), each of
+        those taken one round at a time."""
+        step_s = min((w.plan_s + w.transfer_s + w.dispatch_s + w.fetch_s)
+                     / max(w.computed_steps, 1)
+                     for w in self.warm[1:])
+        return max(1, math.ceil(AHEAD_S / step_s))
+
+    def compile_step(self):
+        """The round step the window ran, compiled ahead of time for the
+        last round's batch (a persistent-cache load after the first run)."""
+        return self.step.lower(self.state, self.batch, self.lr_mult).compile()
+
+    @staticmethod
+    def step_memory(compiled) -> dict:
+        """The compiler's memory analysis of the compiled round step."""
+        ma = compiled.memory_analysis()
         return {k: int(getattr(ma, k)) for k in (
             "peak_memory_in_bytes", "argument_size_in_bytes", "output_size_in_bytes",
             "alias_size_in_bytes", "temp_size_in_bytes")}
@@ -139,7 +184,10 @@ class RunRecord:
     flops_per_token: float
     peak_flops: float                 # per chip, bf16
     chips: int
+    computed_steps: int               # local steps computed over the window
+    cell: spec.Cell                   # shape, traffic and config of the run
     trace: dict | None = None         # trace_reduce.reduce() of a traced window
+    scopes: dict | None = None        # scopes.reduce() of a traced window with device ops
 
     @property
     def useful_tokens(self) -> int:
@@ -174,6 +222,13 @@ def use_benchmark_cache() -> None:
     use_compile_cache()
 
 
+def scope_reduction(xplane: str, hlo_text: str) -> dict | None:
+    """``scopes.reduce`` of a traced window against the round step's HLO;
+    None where the trace holds no device operations (a CPU run)."""
+    st = scopes.load(xplane)
+    return scopes.reduce(st, hlo_text) if any(st.ops) else None
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, t_start: float,
              device_kind: str | None = None, root: str = spec.ROOT) -> dict:
     """Set up, measure, check.  Returns the result object the CLI prints;
@@ -189,6 +244,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, t_start: f
     prog.start(seed)
     prog_readings = prog.warm_up(seed, rounds_checked)
     compile_s = snt.secs
+    ahead = prog.ahead_steps()
 
     cycle = cell.traffic.get("window_cycle")
     tmp = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -201,32 +257,53 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, t_start: f
             gc.callbacks.append(gcs)
             t_w0 = time.perf_counter()
             with annotate("window"):
+                sent = collections.deque()   # (record, metrics) of rounds not yet fetched
+                queued = 0                   # their computed steps
                 while True:
                     i = len(window)
-                    window.append(prog.round(rounds_checked + (i % cycle if cycle else i)))
+                    rec, mets = prog.send(rounds_checked + (i % cycle if cycle else i))
+                    window.append(rec)
+                    sent.append((rec, mets))
+                    queued += rec.computed_steps
+                    # wait for the oldest round only while enough work stands behind it
+                    while queued - sent[0][0].computed_steps >= ahead:
+                        queued -= prog.fetch(*sent.popleft()).computed_steps
                     if (time.perf_counter() - t_w0 >= seconds
                             and not (cycle and len(window) % cycle)):
                         break
+                while sent:
+                    prog.fetch(*sent.popleft())
             t_w1 = time.perf_counter()
             gc.callbacks.remove(gcs)
             compiles_in_window = snt.count - compiles0
-        reduced = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(tmp))) \
-            if trace else None
+        stats = dev.memory_stats() or {}
+        compiled = prog.compile_step()
+        reduced = scoped = None
+        if trace:
+            path = trace_reduce.find_xplane(tmp)
+            reduced = trace_reduce.reduce(trace_reduce.load(path))
+            t_s0 = time.perf_counter()
+            scoped = scope_reduction(path, compiled.as_text())
+            print(f"scopes: {json.dumps(scoped)}", flush=True)
+            print(f"scope reduction: {time.perf_counter() - t_s0!r} s", flush=True)
     finally:
         if tmp is not None:
             shutil.rmtree(tmp, ignore_errors=True)
 
-    stats = dev.memory_stats() or {}
-    step_mem = prog.step_memory()
+    step_mem = prog.step_memory(compiled)
     memory_peak = max(int(stats.get("peak_bytes_in_use", 0)), step_mem["peak_memory_in_bytes"])
     prog.free()
-    slow = max(window, key=lambda w: w.plan_s + w.dispatch_s + w.fetch_s)
+    # a send is the host's own work; a fetch mostly waits for the device's pace
+    slow = max(window, key=lambda w: w.plan_s + w.transfer_s + w.dispatch_s)
     print(f"window: {len(window)} rounds of rounds {min(w.rnd for w in window)}.."
           f"{max(w.rnd for w in window)} in "
-          f"{t_w1 - t_w0!r} s; compiles in window: {compiles_in_window}", flush=True)
-    print(f"slowest round: {slow.rnd}, plan {slow.plan_s!r} s, dispatch {slow.dispatch_s!r} s, "
-          f"fetch {slow.fetch_s!r} s (median round "
-          f"{statistics.median(w.plan_s + w.dispatch_s + w.fetch_s for w in window)!r} s); "
+          f"{t_w1 - t_w0!r} s, {ahead} computed steps kept ahead; "
+          f"compiles in window: {compiles_in_window}", flush=True)
+    print(f"slowest send: {window.index(slow) + 1} of {len(window)}, round {slow.rnd}, "
+          f"plan {slow.plan_s!r} s, transfer {slow.transfer_s!r} s, dispatch {slow.dispatch_s!r} s "
+          f"(median send {statistics.median(w.plan_s + w.transfer_s + w.dispatch_s for w in window)!r}"
+          f" s); "
+          f"longest fetch {max(w.fetch_s for w in window)!r} s; "
           f"gc in window: {gcs.count} collections, longest {gcs.longest!r} s", flush=True)
     print(f"memory: allocator {stats}; round step {step_mem}", flush=True)
 
@@ -240,7 +317,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, t_start: f
         setup_s=t_w0 - t_start, compile_s=compile_s, window_s=t_w1 - t_w0, rounds=window,
         tokens_per_step=prog.fl.local_batch * cell.traffic["seq_len"],
         flops_per_token=train_flops_per_token(cell.shape, cell.traffic["seq_len"]),
-        peak_flops=float(peak["bf16_flops_per_s"]), chips=cell.chips, trace=reduced)
+        peak_flops=float(peak["bf16_flops_per_s"]), chips=cell.chips,
+        computed_steps=sum(w.computed_steps for w in window), cell=cell,
+        trace=reduced, scopes=scoped)
     bench = spec.load_benchmark(root)
     metrics = {}
     for m in spec.metrics_for(bench, cell.name, trace):

@@ -1,5 +1,7 @@
-"""Unmasked local steps over the C * K_max steps the padded cohort scan
-computes, summed over the window's rounds, from the batches' step masks."""
+"""Unmasked local steps over the C * K_max steps the cohort layout holds,
+summed over the window's rounds, from the batches' step masks: a share of the
+layout, not of the compute (a sequential cohort computes each client only to
+its last unmasked step)."""
 
 
 def read(run):

@@ -7,13 +7,16 @@ everything from the seed and the cell's files.
   clients' sizes and token rows from the benchmark's own ``bench/clients.py``;
   the uniform cohort and each client's reshuffled batches from the seeded
   numpy streams that FedShuffle's sampler and reshuffle define.
-* The model — a dense decoder (RMSNorm, rotary embedding on the halves of
-  each head, multi-head attention with optional QKV bias, SwiGLU, tied or
-  separate head), every matrix product at ``Precision.HIGHEST``.
-* ``local_step`` — one SGD step on one batch.  The backward pass runs layer
-  by layer and updates each layer as soon as its gradient is known, so one
-  full-width copy of the gradient never exists and the 1.3B-parameter cell
-  fits one chip.
+* The model — the configuration's family (``bench/families/<family>.py``)
+  gives its layers in order, each a float32 function of its weights and the
+  hidden state that returns the new state and the layer's auxiliary loss;
+  the embedding, final RMSNorm and head (tied or separate) are shared here.
+  Every matrix product is at ``Precision.HIGHEST``.
+* ``local_step`` — one SGD step on one batch of the loss cross entropy +
+  the layers' auxiliary losses.  The backward pass runs layer by layer and
+  updates each layer as soon as its gradient is known, so one full-width
+  copy of the gradient never exists and the 1.3B-parameter cell fits one
+  chip.
 * ``reference_rounds`` — FedShuffle rounds: each cohort client runs its
   K_i local steps at ``local_lr / K_i``; the server adds
   ``server_lr * sum_i (w_i / p_i) (y_i - x)``.
@@ -33,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .clients import client_sizes, token_rows
-from .spec import ModelShape
+from .spec import family_of
 from .weights import change_norms, init_params
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -113,7 +116,7 @@ def fp8_quant(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _mm(quant, a, b):
+def mm(quant, a, b):
     return jnp.matmul(quant(a), quant(b), precision=HIGHEST)
 
 
@@ -121,52 +124,29 @@ def rmsnorm(scale, x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def rope(x, theta):
-    """x [B, T, H, hd]: rotate (first half, second half) of each head."""
-    T, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
-    ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang), F32)[:, None, :]
-    sin = jnp.asarray(np.sin(ang), F32)[:, None, :]
-    x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-
-
-def block(s: ModelShape, quant: Callable, p: dict, h):
-    """One decoder layer in float32; ``p`` holds float32 weights."""
-    Bsz, T, _ = h.shape
-    hd = s.head_dim
-    a = p["attn"]
-    x = rmsnorm(p["ln1"]["scale"], h, s.norm_eps)
-    q, k, v = _mm(quant, x, a["wq"]), _mm(quant, x, a["wk"]), _mm(quant, x, a["wv"])
-    if s.qkv_bias:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = rope(q.reshape(Bsz, T, s.heads, hd), s.rope_theta)
-    k = rope(k.reshape(Bsz, T, s.kv_heads, hd), s.rope_theta)
-    v = v.reshape(Bsz, T, s.kv_heads, hd)
-    rep = s.heads // s.kv_heads
-    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-    scores = jnp.einsum("bthd,bshd->bhts", quant(q), quant(k), precision=HIGHEST) / np.sqrt(hd)
-    causal = np.tril(np.ones((T, T), bool))
-    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhts,bshd->bthd", quant(probs), quant(v), precision=HIGHEST)
-    h = h + _mm(quant, o.reshape(Bsz, T, s.heads * hd), a["wo"])
-    m = p["mlp"]
-    x = rmsnorm(p["ln2"]["scale"], h, s.norm_eps)
-    return h + _mm(quant, jax.nn.silu(_mm(quant, x, m["gate"])) * _mm(quant, x, m["up"]), m["down"])
-
-
 def _f32(tree):
     return jax.tree.map(lambda t: t.astype(F32), tree)
 
 
-def _layer(blocks, l):
-    return jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, l, keepdims=False), blocks)
+def _layer(stack, l):
+    return jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, l, keepdims=False), stack)
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _block_fwd(s, quant, blocks, l, h):
-    return block(s, quant, _f32(_layer(blocks, l)), h)
+def _subtree(tree: dict, path: str):
+    for k in path.split("/"):
+        tree = tree[k]
+    return tree
+
+
+def _replaced(tree: dict, path: str, value) -> dict:
+    """A copy of ``tree`` with the subtree at ``path`` replaced."""
+    head, _, rest = path.partition("/")
+    return {**tree, head: _replaced(tree[head], rest, value) if rest else value}
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _layer_fwd(s, quant, fn, stack, l, h):
+    return fn(s, quant, _f32(_layer(stack, l)), h)
 
 
 @partial(jax.jit, static_argnums=(0, 1))
@@ -176,7 +156,7 @@ def _head_grad(s, quant, h, norm_scale, head, labels):
 
     def loss(h, ns, w):
         x = rmsnorm(ns, h, s.norm_eps)
-        logits = _mm(quant, x, w.T if s.tied else w)
+        logits = mm(quant, x, w.T if s.tied else w)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         return jnp.mean(lse - picked)
@@ -186,15 +166,17 @@ def _head_grad(s, quant, h, norm_scale, head, labels):
     return val, grads
 
 
-@partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,))
-def _block_bwd_update(s, quant, blocks, l, h_in, dh, eta):
-    lp = _layer(blocks, l)
-    _, vjp = jax.vjp(lambda p, h: block(s, quant, p, h), _f32(lp), h_in)
-    dp, dh_in = vjp(dh)
+@partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(3,))
+def _layer_bwd_update(s, quant, fn, stack, l, h_in, dh, eta):
+    """Backward through layer ``l`` of ``stack`` with cotangent (dh, 1): the
+    layer's auxiliary loss enters the loss with weight 1."""
+    lp = _layer(stack, l)
+    _, vjp = jax.vjp(lambda p, h: fn(s, quant, p, h), _f32(lp), h_in)
+    dp, dh_in = vjp((dh, jnp.ones((), F32)))
     new = jax.tree.map(lambda w, g: (w.astype(F32) - eta * g).astype(w.dtype), lp, dp)
-    blocks = jax.tree.map(lambda t, n: jax.lax.dynamic_update_index_in_dim(t, n, l, 0),
-                          blocks, new)
-    return blocks, dh_in
+    stack = jax.tree.map(lambda t, n: jax.lax.dynamic_update_index_in_dim(t, n, l, 0),
+                         stack, new)
+    return stack, dh_in
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -213,33 +195,39 @@ def _embed_lookup(embed, inputs):
     return embed[inputs].astype(F32)
 
 
-def local_step(s: ModelShape, params: dict, tokens, eta: float,
+def local_step(s, params: dict, tokens, eta: float,
                quant: Callable = no_quant) -> tuple[dict, float]:
     """One SGD step on ``tokens`` [B, seq_len + 1]; returns the new params
-    (``params`` is consumed) and the step's mean next-token cross entropy."""
+    (``params`` is consumed) and the step's loss: mean next-token cross
+    entropy plus the layers' auxiliary losses."""
     tokens = jnp.asarray(tokens)
     inputs, labels = tokens[:, :-1], tokens[:, 1:]
     eta = jnp.float32(eta)
-    blocks = params["blocks"]
+    order = family_of(s).layers(s)
+    stacks = {path: _subtree(params, path) for path, _, _ in order}
     h = _embed_lookup(params["embed"], inputs)
-    saved = []
-    for l in range(s.layers):
+    saved, aux = [], jnp.float32(0.0)
+    for path, l, fn in order:
         saved.append(h)
-        h = _block_fwd(s, quant, blocks, jnp.int32(l), h)
+        h, a = _layer_fwd(s, quant, fn, stacks[path], jnp.int32(l), h)
+        aux = aux + a
     head = params["embed"] if s.tied else params["lm_head"]
     loss, (dh, d_norm, d_head) = _head_grad(s, quant, h, params["final_norm"]["scale"],
                                             head, labels)
-    for l in reversed(range(s.layers)):
-        blocks, dh = _block_bwd_update(s, quant, blocks, jnp.int32(l), saved[l], dh, eta)
-    out = {"blocks": blocks,
-           "final_norm": {"scale": _sgd(params["final_norm"]["scale"], d_norm, eta)}}
+    for (path, l, fn), h_in in zip(reversed(order), reversed(saved)):
+        stacks[path], dh = _layer_bwd_update(s, quant, fn, stacks[path], jnp.int32(l),
+                                             h_in, dh, eta)
+    out = params
+    for path, stack in stacks.items():
+        out = _replaced(out, path, stack)
+    out = {**out, "final_norm": {"scale": _sgd(params["final_norm"]["scale"], d_norm, eta)}}
     if s.tied:
         out["embed"] = _embed_update(params["embed"], d_head, inputs, dh, eta)
     else:
         out["lm_head"] = _sgd(params["lm_head"], d_head, eta)
         out["embed"] = _embed_update(params["embed"], jnp.zeros(params["embed"].shape, F32),
                                      inputs, dh, eta)
-    return out, float(loss)
+    return out, float(loss + aux)
 
 
 @jax.jit
@@ -272,7 +260,7 @@ class Readings(NamedTuple):
     inputs: list            # per round: [(client, tokens [K_i, B, T+1])]
 
 
-def reference_rounds(s: ModelShape, traffic: dict, seed: int, task_seed: int,
+def reference_rounds(s, traffic: dict, seed: int, task_seed: int,
                      rounds: int, quant: Callable = no_quant,
                      rows: int | None = None) -> Readings:
     """Run ``rounds`` FedShuffle rounds from the weights of ``seed``.

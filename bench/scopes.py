@@ -24,17 +24,14 @@ the round step's compiled HLO text and gives
 * ``h2d_bytes``: the ``bytes`` stats of the window's ``data/to_device``
   spans, summed.
 
-``per_layer`` turns that into the per-layer metrics, and
-``python3 -m bench.scopes --workload <cell> --seed <n> --seconds <s>`` runs
-a cell's window traced on the chip and prints them.
+``per_layer`` turns that into the per-layer metrics, which the readers
+``bench/metrics/<name>.py`` take from a traced run's record (``read_metric``);
+a traced ``bench.run`` also prints the whole reduction on a ``scopes:`` line.
 """
 from __future__ import annotations
 
-import argparse
 import bisect
-import json
 import re
-import sys
 from collections import defaultdict
 from typing import NamedTuple
 
@@ -134,7 +131,8 @@ def load(path: str) -> ScopeTrace:
     ops, modules, window, data = [], [], None, []
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith(DEVICE_PLANE) and plane.name[len(DEVICE_PLANE):].isdigit():
-            lines = {line.name: [span(e) for e in line.events] for line in plane.lines}
+            lines = {line.name: [span(e) for e in line.events] for line in plane.lines
+                     if line.name in (OPS_LINE, MODULES_LINE)}
             ops.append(lines.get(OPS_LINE, []))
             modules.append(lines.get(MODULES_LINE, []))
         elif plane.name.startswith("/host:"):
@@ -223,16 +221,17 @@ def reduce(trace: ScopeTrace, hlo_text: str) -> dict:
     }
 
 
-def per_layer(red: dict, rounds: int, padded_steps: int) -> dict:
-    """The per-layer metrics of a window of ``rounds`` rounds that computed
-    ``padded_steps`` local steps (C * K_max a round, masked steps included).
-    A metric whose scope the program does not name is left out."""
+def per_layer(red: dict, rounds: int, computed_steps: int) -> dict:
+    """The per-layer metrics of a window of ``rounds`` rounds in which the
+    program computed ``computed_steps`` local steps (``data/local_steps``
+    ``computed``: each client up to its last unmasked step).  A metric whose
+    scope the program does not name is left out."""
     sc = red["scopes"]
     out = {}
     for metric, scope in (("local_step_ms", "local_step"), ("lm_head_ms", "lm_head"),
                           ("local_apply_ms", "local_apply")):
         if scope in sc:
-            out[metric] = 1e3 * sc[scope] / padded_steps
+            out[metric] = 1e3 * sc[scope] / computed_steps
     if "accumulate" in sc:
         out["accumulate_ms"] = 1e3 * (sc["accumulate"] + sc.get("client_delta", 0.0)) / rounds
     if "server_update" in sc:
@@ -243,68 +242,9 @@ def per_layer(red: dict, rounds: int, padded_steps: int) -> dict:
     return out
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    args = ap.parse_args(argv)
-
-    import shutil
-    import tempfile
-    import time
-
-    import jax
-
-    from . import harness, spec
-
-    cell = spec.load_cell(args.workload)
-    if jax.devices()[0].platform != "tpu":
-        print("bench.scopes: needs a TPU", file=sys.stderr)
-        return 2
-    harness.use_benchmark_cache()
-    prog = harness.Program(cell)
-    prog.start(args.seed)
-    first = int(cell.limits["rounds"])
-    for r in range(first):
-        prog.round(r)
-    cycle = cell.traffic.get("window_cycle")
-    tmp = tempfile.mkdtemp(prefix="bench-scopes-")
-    window: list = []
-    try:
-        with jax.profiler.trace(tmp, profiler_options=harness.profiler_options()):
-            t0 = time.perf_counter()
-            with harness.annotate("window"):
-                while True:
-                    i = len(window)
-                    window.append(prog.round(first + (i % cycle if cycle else i)))
-                    if (time.perf_counter() - t0 >= args.seconds
-                            and not (cycle and len(window) % cycle)):
-                        break
-            t1 = time.perf_counter()
-        path = trace_reduce.find_xplane(tmp)
-        hlo = prog.step.lower(prog.state, prog.batch, prog.lr_mult).compile().as_text()
-        scopes_of = scope_map(hlo)
-        red = reduce(load(path), hlo)
-        base = trace_reduce.reduce(trace_reduce.load(path))
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    rounds = len(window)
-    padded = sum(w.padded_steps for w in window)
-    print(json.dumps({
-        "workload": cell.name, "seed": args.seed, "rounds": rounds,
-        "padded_steps": padded, "window_s": t1 - t0,
-        "client_tokens_per_s": sum(w.useful_steps for w in window)
-        * prog.fl.local_batch * cell.traffic["seq_len"] / (t1 - t0),
-        "busy_s": base["busy_s"], "traced_window_s": base["window_s"],
-        "per_layer": per_layer(red, rounds, padded),
-        "reduced": red, "idle_gaps": base["idle_gaps"],
-        # the breakdown's costliest ops, each with its scopes
-        "device_ops": [[name, t, sorted(scopes_of.get(name.split(" ")[0], ()))]
-                       for name, t in base["device_ops"]],
-    }), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())
+def read_metric(run, name: str) -> float | None:
+    """``per_layer``'s ``name`` for a run record (``bench/harness.py``
+    ``RunRecord``); None without a scope reduction or without that scope."""
+    if run.scopes is None:
+        return None
+    return per_layer(run.scopes, len(run.rounds), run.computed_steps).get(name)

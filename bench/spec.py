@@ -3,62 +3,60 @@
 Every configuration, traffic mix, cell limit and metric lives in a file of its
 own, found by its name:
 
-* ``configs[].file``            — the model's sizes as run (``ModelShape``);
+* ``configs[].file``            — the model's sizes as run, with its
+  ``family``;
+* ``bench/families/<family>.py`` — that kind of model: its ``Shape``, weight
+  layout, reference layers, FLOP count and the program's ``ArchConfig``
+  (see ``bench/families/dense.py``);
 * ``bench/traffic/<traffic>.json`` — the federated job's parameters;
 * ``bench/limits/<cell>.json``  — the limits of the comparison that decides
   ``correct`` in that cell;
 * ``bench/metrics/<metric>.py`` — a reader ``read(run) -> float | None``.
 
-A new cell, configuration, traffic mix or metric is a new file and an entry
-in ``BENCHMARK.json``; no code here changes.
+A new cell, configuration, model family, traffic mix or metric is a new file
+and an entry in ``BENCHMARK.json``; no code here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+import re
+import sys
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Callable
 
 from . import ROOT
 
 
-@dataclass(frozen=True)
-class ModelShape:
-    """A dense decoder's sizes, as the configuration file states them."""
+def family(name: str, root: str = ROOT) -> ModuleType:
+    """The module ``bench/families/<name>.py`` under ``root``, loaded once
+    per file (its ``Shape`` class is a ``jax.jit`` static argument)."""
+    return _load_family(os.path.abspath(os.path.join(root, "bench", "families", name + ".py")))
 
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    qkv_bias: bool
-    tied: bool
-    rope_theta: float
-    norm_eps: float
-    dtype: str
-    init_std: float
 
-    @classmethod
-    def from_config(cls, cfg: dict) -> "ModelShape":
-        heads = int(cfg["num_attention_heads"])
-        return cls(
-            layers=int(cfg["num_hidden_layers"]),
-            d_model=int(cfg["hidden_size"]),
-            heads=heads,
-            kv_heads=int(cfg.get("num_key_value_heads", heads)),
-            head_dim=int(cfg.get("head_dim", int(cfg["hidden_size"]) // heads)),
-            d_ff=int(cfg["intermediate_size"]),
-            vocab=int(cfg["vocab_size"]),
-            qkv_bias=bool(cfg["qkv_bias"]),
-            tied=bool(cfg["tie_word_embeddings"]),
-            rope_theta=float(cfg["rope_theta"]),
-            norm_eps=float(cfg["rms_norm_eps"]),
-            dtype=str(cfg["torch_dtype"]),
-            init_std=float(cfg["assumed"]["init_std"]),
-        )
+@functools.cache
+def _load_family(path: str) -> ModuleType:
+    name = "bench_family_" + re.sub(r"\W", "_", path)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod        # dataclasses and ``family_of`` look it up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family_of(shape) -> ModuleType:
+    """The family module whose ``Shape`` ``shape`` is."""
+    return sys.modules[type(shape).__module__]
+
+
+def __getattr__(name: str):
+    # ``ModelShape``: the dense family's shape under its older name
+    if name == "ModelShape":
+        return family("dense").Shape
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 @dataclass(frozen=True)
@@ -71,10 +69,11 @@ class Cell:
     config: dict
     traffic: dict
     limits: dict
+    family: ModuleType    # bench/families/<config's "family">.py
 
     @property
-    def shape(self) -> ModelShape:
-        return ModelShape.from_config(self.config)
+    def shape(self):
+        return self.family.Shape.from_config(self.config)
 
 
 def _json(path: str) -> dict:
@@ -99,6 +98,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         name=name, chips=int(w["chips"]), config_name=w["config"], config=cfg,
         traffic=_json(os.path.join(bench_dir, "traffic", w["traffic"] + ".json")),
         limits=_json(os.path.join(bench_dir, "limits", name + ".json")),
+        family=family(cfg["family"], root),
     )
 
 

@@ -24,10 +24,13 @@ def _no_persistent_cache(monkeypatch):
 
 @pytest.fixture
 def tiny_root(tmp_path):
-    """A benchmark root holding the tiny CPU cells, the real metric readers
-    and peaks, and one extra metric file (``dummy_rounds``)."""
+    """A benchmark root holding the tiny CPU cells, the real model families
+    (beside the test-only ``hetero``), metric readers and peaks, and one extra
+    metric file (``dummy_rounds``)."""
     root = tmp_path / "root"
     shutil.copytree(os.path.join(DATA, "tiny"), root)
+    shutil.copytree(os.path.join(ROOT, "bench", "families"), root / "bench" / "families",
+                    dirs_exist_ok=True)
     shutil.copytree(os.path.join(ROOT, "bench", "metrics"), root / "bench" / "metrics")
     shutil.copy(os.path.join(ROOT, "bench", "peaks.json"), root / "bench" / "peaks.json")
     (root / "bench" / "metrics" / "dummy_rounds.py").write_text(

@@ -8,26 +8,38 @@ import pytest
 from bench import compare, harness, reference, spec, weights
 
 
-def test_layerwise_step_matches_autodiff(tiny_root):
-    cell = spec.load_cell("tiny-lognormal", tiny_root)
+@pytest.mark.parametrize("name", ["tiny-lognormal", "tiny-hetero"])
+def test_layerwise_step_matches_autodiff(tiny_root, name):
+    """The layer-by-layer step against ``jax.grad`` of the whole loss, cross
+    entropy plus every layer's auxiliary loss: a dense stack (tied head, no
+    auxiliary loss) and the test-only ``hetero`` family (a first layer of
+    another width under its own subtree, a nonzero auxiliary loss in every
+    layer, separate head)."""
+    cell = spec.load_cell(name, tiny_root)
     s = cell.shape
     toks = reference.round_plan(cell.traffic, s.vocab, 7, 0)[0].tokens[0]
     x = weights.init_params(s, 7)
 
     def loss(p):
         h = p["embed"][toks[:, :-1]]
-        for l in range(s.layers):
-            h = reference.block(s, reference.no_quant, jax.tree.map(lambda t: t[l], p["blocks"]), h)
+        aux = 0.0
+        for path, l, fn in cell.family.layers(s):
+            stack = weights.nest({k[len(path) + 1:]: v for k, v in weights.flatten(p).items()
+                                  if k.startswith(path + "/")})
+            h, a = fn(s, reference.no_quant, jax.tree.map(lambda t: t[l], stack), h)
+            aux = aux + a
         h = reference.rmsnorm(p["final_norm"]["scale"], h, s.norm_eps)
-        logits = jnp.matmul(h, p["embed"].T, precision=reference.HIGHEST)
+        logits = jnp.matmul(h, p["embed"].T if s.tied else p["lm_head"],
+                            precision=reference.HIGHEST)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        return jnp.mean(lse - jnp.take_along_axis(logits, toks[:, 1:, None], -1)[..., 0])
+        return jnp.mean(lse - jnp.take_along_axis(logits, toks[:, 1:, None], -1)[..., 0]) + aux
 
     want_loss, g = jax.value_and_grad(loss)(x)
     want = jax.tree.map(lambda w, d: w - 0.1 * d, x, g)
     with jax.default_matmul_precision("highest"):
         got, got_loss = reference.local_step(s, jax.tree.map(jnp.copy, x), toks, 0.1)
     assert abs(got_loss - float(want_loss)) < 1e-6
+    assert set(weights.flatten(got)) == set(weights.flatten(want))
     for k, v in weights.flatten(want).items():
         np.testing.assert_allclose(weights.flatten(got)[k], v, rtol=1e-5, atol=1e-7)
 

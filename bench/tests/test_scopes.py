@@ -2,10 +2,12 @@
 per named scope inside the round step's own module, device idle inside the
 data plane's spans, and the bytes the program handed the device."""
 import os
+from types import SimpleNamespace
 
 import pytest
 
 from bench import scopes as sc
+from bench import spec
 from bench.scopes import HostSpan, ScopeTrace
 from bench.trace_reduce import WINDOW, Span
 
@@ -99,7 +101,7 @@ def test_reduce_counts_self_time_per_scope_inside_the_module():
 
 def test_per_layer_metrics_and_a_program_without_scopes():
     red = sc.reduce(synthetic(), HLO)
-    m = sc.per_layer(red, rounds=2, padded_steps=8)
+    m = sc.per_layer(red, rounds=2, computed_steps=8)
     assert m == {"local_step_ms": pytest.approx(500.0), "lm_head_ms": pytest.approx(375.0),
                  "local_apply_ms": pytest.approx(75.0), "accumulate_ms": pytest.approx(750.0),
                  "server_update_ms": pytest.approx(500.0), "data_wait_ms": pytest.approx(650.0),
@@ -110,7 +112,31 @@ def test_per_layer_metrics_and_a_program_without_scopes():
     t = synthetic()
     red = sc.reduce(ScopeTrace(t.ops, t.modules, t.window, []), bare)
     assert red["scopes"] == {} and red["other_s"] == pytest.approx(7.0)
-    assert sc.per_layer(red, rounds=2, padded_steps=8) == {}
+    assert sc.per_layer(red, rounds=2, computed_steps=8) == {}
+
+
+def test_per_step_metrics_divide_by_computed_steps():
+    """The same device time over fewer computed steps (a sequential cohort
+    computes each client only to its last unmasked step) reads more per
+    step; the per-round metrics do not move."""
+    red = sc.reduce(synthetic(), HLO)
+    full, trimmed = (sc.per_layer(red, rounds=2, computed_steps=n) for n in (8, 2))
+    for m in ("local_step_ms", "lm_head_ms", "local_apply_ms"):
+        assert trimmed[m] == pytest.approx(4 * full[m])
+    for m in ("accumulate_ms", "server_update_ms", "data_wait_ms", "h2d_bytes"):
+        assert trimmed[m] == full[m]
+
+
+SEVEN = ("local_step_ms", "lm_head_ms", "local_apply_ms", "accumulate_ms",
+         "server_update_ms", "data_wait_ms", "h2d_bytes")
+
+
+def test_readers_of_a_run_record():
+    red = sc.reduce(synthetic(), HLO)
+    run = SimpleNamespace(scopes=red, rounds=[None, None], computed_steps=8)
+    got = {m: spec.metric_reader(m)(run) for m in SEVEN}
+    assert got == sc.per_layer(red, rounds=2, computed_steps=8)
+    assert all(spec.metric_reader(m)(SimpleNamespace(scopes=None)) is None for m in SEVEN)
 
 
 def test_no_window_or_no_device_ops_is_an_error():
@@ -152,5 +178,15 @@ def test_recorded_chip_trace():
     assert red["data_wait_by_span"]["data/materialize"] == pytest.approx(
         (10.952560 + 10.877410 + 10.860499) * 1e-3, rel=1e-6)
     assert red["h2d_bytes"] == 3 * 4 * 256 * 1024 * 4
-    m = sc.per_layer(red, rounds=3, padded_steps=12)
+    m = sc.per_layer(red, rounds=3, computed_steps=12)
     assert m["h2d_bytes"] == 4194304 and "local_apply_ms" not in m
+    # the readers of the result line: the values above, 12 computed steps
+    run = SimpleNamespace(scopes=red, rounds=[None] * 3, computed_steps=12)
+    got = {name: spec.metric_reader(name)(run) for name in SEVEN}
+    assert got == {
+        "local_step_ms": pytest.approx(83.229e-3 / 12, rel=1e-9),
+        "lm_head_ms": pytest.approx(76.986e-3 / 12, rel=1e-9),
+        "local_apply_ms": None, "accumulate_ms": None,
+        "server_update_ms": pytest.approx(12.227e-3 / 3, rel=1e-9),
+        "data_wait_ms": pytest.approx(51.042179 / 3, rel=1e-9),
+        "h2d_bytes": 4194304.0}

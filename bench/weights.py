@@ -1,9 +1,10 @@
-"""Seeded weights of a dense decoder, made on the device in one jitted call.
+"""Seeded weights of a model, made on the device in one jitted call.
 
-The tree has the layout the program's dense model takes (``embed``, layers
-stacked on a leading axis under ``blocks``, ``final_norm``), so the same
-weights feed the program and the reference.  Both make them here, from the
-seed alone: the reference takes nothing the program made.
+The tree has the layout the program's model takes, as the family's
+``leaf_shapes`` lists it (``embed``, layers stacked on a leading axis,
+``final_norm``, ``lm_head`` when untied), so the same weights feed the
+program and the reference.  Both make them here, from the seed alone: the
+reference takes nothing the program made.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .spec import ModelShape
+from .spec import family_of
 
 
 def seed_words(seed: int) -> tuple[np.uint32, np.uint32]:
@@ -21,32 +22,6 @@ def seed_words(seed: int) -> tuple[np.uint32, np.uint32]:
     if seed < 0 or seed >= 2 ** 64:
         raise ValueError(f"seed {seed} is outside [0, 2**64)")
     return np.uint32(seed & 0xFFFFFFFF), np.uint32(seed >> 32)
-
-
-def leaf_shapes(s: ModelShape) -> dict:
-    """``{path: (shape, kind)}`` with kind one of normal / ones."""
-    L, D, F, V = s.layers, s.d_model, s.d_ff, s.vocab
-    q, kv = s.heads * s.head_dim, s.kv_heads * s.head_dim
-    leaves = {
-        "embed": ((V, D), "normal"),
-        "blocks/ln1/scale": ((L, D), "ones"),
-        "blocks/attn/wq": ((L, D, q), "normal"),
-        "blocks/attn/wk": ((L, D, kv), "normal"),
-        "blocks/attn/wv": ((L, D, kv), "normal"),
-        "blocks/attn/wo": ((L, q, D), "normal"),
-        "blocks/ln2/scale": ((L, D), "ones"),
-        "blocks/mlp/gate": ((L, D, F), "normal"),
-        "blocks/mlp/up": ((L, D, F), "normal"),
-        "blocks/mlp/down": ((L, F, D), "normal"),
-        "final_norm/scale": ((D,), "ones"),
-    }
-    if s.qkv_bias:
-        leaves.update({"blocks/attn/bq": ((L, q), "normal"),
-                       "blocks/attn/bk": ((L, kv), "normal"),
-                       "blocks/attn/bv": ((L, kv), "normal")})
-    if not s.tied:
-        leaves["lm_head"] = ((D, V), "normal")
-    return leaves
 
 
 def nest(flat: dict) -> dict:
@@ -72,11 +47,11 @@ def flatten(tree: dict, prefix: str = "") -> dict:
 
 
 @partial(jax.jit, static_argnums=(0,))
-def _init(s: ModelShape, lo, hi):
+def _init(s, lo, hi):
     key = jax.random.fold_in(jax.random.fold_in(jax.random.PRNGKey(0), lo), hi)
     dt = jnp.dtype(s.dtype)
     flat = {}
-    for i, (path, (shape, kind)) in enumerate(sorted(leaf_shapes(s).items())):
+    for i, (path, (shape, kind)) in enumerate(sorted(family_of(s).leaf_shapes(s).items())):
         if kind == "ones":
             flat[path] = jnp.ones(shape, dt)
         else:
@@ -85,8 +60,11 @@ def _init(s: ModelShape, lo, hi):
     return nest(flat)
 
 
-def init_params(s: ModelShape, seed: int) -> dict:
-    """The weights of ``seed``, in the configuration's dtype, on the device."""
+def init_params(s, seed: int) -> dict:
+    """The weights of ``seed`` for the family shape ``s``, in the
+    configuration's dtype, on the device: each leaf ``normal`` draws
+    N(0, init_std^2) from the seed folded with its index in the sorted leaf
+    list, ``ones`` is ones."""
     return _init(s, *seed_words(seed))
 
 
@@ -97,7 +75,7 @@ def _diff_norms(params, x0):
             for k in x0}
 
 
-def change_norms(s: ModelShape, params: dict, seed: int) -> dict:
+def change_norms(s, params: dict, seed: int) -> dict:
     """``{leaf path: ||params - init(seed)||_2}`` as host floats.
 
     The initial weights come out of their own jitted call, so they are
