@@ -21,14 +21,35 @@ Family = Literal["dense", "moe", "ssm", "hybrid", "encdec", "vlm", "audio"]
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int               # routed experts
+    """Routed experts with a softmax top-k router (DeepSeekMoE,
+    arXiv:2401.06066 / 2405.04434).  The layer may hold a share of the routed
+    experts, as one chip of an expert-parallel deployment does: the router
+    scores all ``num_experts``, and the layer computes the part of the result
+    that its ``held`` experts ``first_held .. first_held + held - 1`` give."""
+
+    num_experts: int               # routed experts the router scores
     top_k: int
     num_shared: int = 0            # shared (always-on) experts
     expert_ff: int = 0             # per-expert FFN hidden dim
-    capacity_factor: float = 1.25
-    group_size: int = 1024         # tokens per dispatch group (GShard-style)
-    scan_groups: bool = False      # lax.scan over groups (bounds dispatch memory)
-    aux_coef: float = 0.01         # load-balance auxiliary loss weight
+    held: int = 0                  # routed experts this layer holds; 0 => all
+    first_held: int = 0            # index of the first held expert
+    aux_alpha: float = 0.001       # weight of the sequence-wise balance loss
+
+    def held_experts(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling as DeepSeek-V2 defines it (arXiv:2309.00071;
+    ``rope_scaling`` of the published config)."""
+
+    factor: float = 40.0
+    original_max: int = 4096       # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -40,6 +61,7 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    yarn: YaRNConfig | None = None  # None => plain rope, softmax scale 1/sqrt(qk)
 
 
 @dataclass(frozen=True)
@@ -62,7 +84,8 @@ class ArchConfig:
     citation: str = ""
 
     # core transformer dims
-    n_layers: int = 2
+    n_layers: int = 2              # every layer, the leading dense ones included
+    first_dense: int = 0           # leading layers of a moe stack with a dense FFN (d_ff)
     d_model: int = 256
     n_heads: int = 4
     n_kv_heads: int = 4
@@ -113,6 +136,7 @@ class ArchConfig:
         """A tiny same-family variant for CPU smoke tests (<=2 layers etc.)."""
         small: dict = dict(
             n_layers=2,
+            first_dense=min(self.first_dense, 1),
             d_model=min(self.d_model, 128),
             n_heads=min(self.n_heads, 4),
             d_ff=min(self.d_ff, 256),
@@ -127,10 +151,8 @@ class ArchConfig:
                 top_k=min(self.moe.top_k, 2),
                 num_shared=min(self.moe.num_shared, 1),
                 expert_ff=min(self.moe.expert_ff, 128),
-                group_size=64,
-                # effectively dropless at smoke scale: capacity-dropping is a
-                # lossy production trade-off, not something tests should see
-                capacity_factor=8.0,
+                held=0,
+                first_held=0,
             )
         if self.mla is not None:
             small["mla"] = dataclasses.replace(

@@ -17,8 +17,7 @@ CONFIG = ArchConfig(
     d_ff=18432,             # dense-FFN width of the first layers (V3: 3 dense)
     vocab=129280,
     mla=MLAConfig(q_lora=1536, kv_lora=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128),
-    moe=MoEConfig(num_experts=256, top_k=8, num_shared=1, expert_ff=2048, group_size=1024,
-                  scan_groups=True),
+    moe=MoEConfig(num_experts=256, top_k=8, num_shared=1, expert_ff=2048),
     mtp=True,
     remat="full",
 )

@@ -43,12 +43,6 @@ PAIRS = {
     "deepseek": ("deepseek-v3-671b", "prefill_32k", [
         ("it1-seqshard", {"opt_seq_shard": True},
          "per-layer activation all-reduce of [B,32k,7168] dominates; RS+AG halves it"),
-        ("it2-groups", {"opt_seq_shard": True, "moe": "g512"},
-         "smaller dispatch groups shrink the [g,E,C] one-hot and its all-to-all"),
-        ("it3-groups-only", {"moe": "g512"},
-         "it1 was refuted (XLA resharding); retry smaller groups WITHOUT seq-shard"),
-        ("it4-capacity", {"moe": "g512cap1"},
-         "capacity_factor 1.25->1.0 trims [E,C,D] dispatch tensors and their a2a by 20%"),
         ("it5-seqinput", {"__setup__": {"seq_over_model": True}},
          "shard the 32k token dim over the model axis at the INPUT (not per-layer "
          "constraints): XLA propagates seq-sharding; attention gathers only locally"),
@@ -59,10 +53,6 @@ PAIRS = {
 def _resolve(arch_name: str, overrides: dict):
     cfg = get_arch(arch_name)
     ov = {k: v for k, v in overrides.items() if k != "__setup__"}
-    if ov.get("moe") == "g512":
-        ov["moe"] = dataclasses.replace(cfg.moe, group_size=512)
-    elif ov.get("moe") == "g512cap1":
-        ov["moe"] = dataclasses.replace(cfg.moe, group_size=512, capacity_factor=1.0)
     return dataclasses.replace(cfg, **ov)
 
 

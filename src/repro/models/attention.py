@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .layers import apply_rope, dense_init, rmsnorm, rmsnorm_init
+from .layers import apply_rope, dense_init, rmsnorm, rmsnorm_init, yarn_frequencies, yarn_mscale
 
 CHUNK_THRESHOLD = 2048
 Q_CHUNK = 1024
@@ -56,14 +56,16 @@ def _attend_block(q, k, v, mask, scale):
 
 
 def attend(q, k, v, q_pos, kv_pos, *, causal=True, window=0, kv_valid=None,
-           banded=False):
+           banded=False, scale=None):
     """Full attention with optional query chunking.
 
     q [B,Tq,H,hd]; k,v [B,Tk,KV,hd]; q_pos [Tq]; kv_pos [Tk];
-    kv_valid optional bool [B,Tk] (decode cache validity).
+    kv_valid optional bool [B,Tk] (decode cache validity); ``scale`` of the
+    scores, 1/sqrt(hd) when None.
     """
     B, Tq, H, hd = q.shape
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
 
     def mask_for(qp):
         m = band_mask(qp, kv_pos, window=window, causal=causal)  # [tq, Tk]
@@ -236,6 +238,30 @@ def mla_init(key, cfg: ArchConfig, dtype):
     return p
 
 
+def _mla_rope(x, positions, cfg):
+    """Rotate MLA's rope part [..., T, heads, qk_rope_dim]: plain rope, or
+    DeepSeek-V2's YaRN frequencies with cos/sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    y = cfg.mla.yarn
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta, "full")
+    ratio = yarn_mscale(y.factor, y.mscale) / yarn_mscale(y.factor, y.mscale_all_dim)
+    if ratio != 1.0:   # the rotation is linear: scaling cos and sin scales x
+        x = x * jnp.asarray(ratio, x.dtype)
+    inv_freq = yarn_frequencies(cfg.mla.qk_rope_dim, cfg.rope_theta, y)
+    return apply_rope(x, positions, cfg.rope_theta, "full", inv_freq=inv_freq)
+
+
+def mla_softmax_scale(cfg) -> float | None:
+    """MLA's score scale: 1/sqrt(qk) (None, ``attend``'s default) or, with
+    YaRN, mscale(factor, mscale_all_dim)^2 / sqrt(qk)."""
+    m = cfg.mla
+    if m.yarn is None:
+        return None
+    ms = yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim)
+    return ms * ms / (m.qk_nope_dim + m.qk_rope_dim) ** 0.5
+
+
 def _mla_q(params, cfg, x):
     m = cfg.mla
     B, T, _ = x.shape
@@ -252,7 +278,7 @@ def _mla_q(params, cfg, x):
 def _mla_ckv(params, cfg, x, positions):
     c_kv = rmsnorm(params["kv_norm"], x @ params["wdkv"], cfg.norm_eps)     # [B,T,kv_lora]
     k_rope = (x @ params["wkr"])[:, :, None, :]                              # [B,T,1,rope]
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, "full")[:, :, 0]  # [B,T,rope]
+    k_rope = _mla_rope(k_rope, positions, cfg)[:, :, 0]                     # [B,T,rope]
     return c_kv, k_rope
 
 
@@ -265,7 +291,7 @@ def mla_forward(params, cfg: ArchConfig, x, positions, *, window=0):
     B, T, _ = x.shape
     H = cfg.n_heads
     q_nope, q_rope = _mla_q(params, cfg, x)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, "full")
+    q_rope = _mla_rope(q_rope, positions, cfg)
     c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
     k_nope = (c_kv @ params["wuk"]).reshape(B, T, H, m.qk_nope_dim)
     v = (c_kv @ params["wuv"]).reshape(B, T, H, m.v_head_dim)
@@ -273,7 +299,7 @@ def mla_forward(params, cfg: ArchConfig, x, positions, *, window=0):
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], q_rope.shape[:3] + (m.qk_rope_dim,))], axis=-1)
     out = attend(q, k, v, positions, positions, causal=True, window=window,
-                 banded=cfg.opt_banded_window)
+                 banded=cfg.opt_banded_window, scale=mla_softmax_scale(cfg))
     return out.reshape(B, T, -1) @ params["wo"], (c_kv, k_rope)
 
 
@@ -285,7 +311,7 @@ def mla_decode(params, cfg: ArchConfig, x, pos, cache, *, ring=False):
     B = x.shape[0]
     H = cfg.n_heads
     q_nope, q_rope = _mla_q(params, cfg, x)                                  # [B,1,H,*]
-    q_rope = apply_rope(q_rope, jnp.full((1,), pos, jnp.int32), cfg.rope_theta, "full")
+    q_rope = _mla_rope(q_rope, jnp.full((1,), pos, jnp.int32), cfg)
     c_new, kr_new = _mla_ckv(params, cfg, x, jnp.full((1,), pos, jnp.int32))
     S = cache["c_kv"].shape[1]
     slot = (pos % S) if ring else pos
@@ -298,7 +324,9 @@ def mla_decode(params, cfg: ArchConfig, x, pos, cache, *, ring=False):
     q_c = jnp.einsum("bqhn,lhn->bqhl", q_nope, wuk)                          # absorb W_uk
     scores = jnp.einsum("bqhl,bsl->bhqs", q_c, c_kv)
     scores = scores + jnp.einsum("bqhr,bsr->bhqs", q_rope, k_rope)
-    scale = 1.0 / jnp.sqrt(m.qk_nope_dim + m.qk_rope_dim).astype(jnp.float32)
+    scale = mla_softmax_scale(cfg)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(m.qk_nope_dim + m.qk_rope_dim).astype(jnp.float32)
     scores = scores.astype(jnp.float32) * scale
     scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(c_kv.dtype)

@@ -50,7 +50,21 @@ def dense_block_decode(params, cfg: ArchConfig, h, pos, cache, *, window=0, ring
     return h, kv
 
 
-# -- moe (MLA attention + MoE FFN) -------------------------------------------
+# -- moe (MLA attention + MoE FFN; leading layers MLA + dense FFN) ------------
+
+
+def _mla(params, cfg: ArchConfig, h, positions, window):
+    with jax.named_scope("mla"):
+        a, (c_kv, k_rope) = mla_forward(params["attn"], cfg,
+                                        rmsnorm(params["ln1"], h, cfg.norm_eps),
+                                        positions, window=window)
+    return h + a, {"c_kv": c_kv, "k_rope": k_rope}
+
+
+def _mla_decode(params, cfg: ArchConfig, h, pos, cache, ring):
+    a, kv = mla_decode(params["attn"], cfg, rmsnorm(params["ln1"], h, cfg.norm_eps),
+                       pos, cache, ring=ring)
+    return h + a, kv
 
 
 def moe_block_init(key, cfg: ArchConfig, dtype):
@@ -64,22 +78,38 @@ def moe_block_init(key, cfg: ArchConfig, dtype):
 
 
 def moe_block_forward(params, cfg: ArchConfig, h, positions, *, window=0, keep_cache=True):
-    a, (c_kv, k_rope) = mla_forward(params["attn"], cfg, rmsnorm(params["ln1"], h, cfg.norm_eps),
-                                    positions, window=window)
-    h = h + a
-    y, aux = moe_forward(params["moe"], cfg, rmsnorm(params["ln2"], h, cfg.norm_eps))
-    h = h + y
-    cache = {"c_kv": c_kv, "k_rope": k_rope} if keep_cache else None
-    return h, aux, cache
+    """-> (h, {"aux": balance loss, "moe_rows": rows computed}, cache)."""
+    h, cache = _mla(params, cfg, h, positions, window)
+    with jax.named_scope("moe"):
+        y, aux, rows = moe_forward(params["moe"], cfg, rmsnorm(params["ln2"], h, cfg.norm_eps))
+    return h + y, {"aux": aux, "moe_rows": rows}, (cache if keep_cache else None)
 
 
 def moe_block_decode(params, cfg: ArchConfig, h, pos, cache, *, ring=False):
-    a, kv = mla_decode(params["attn"], cfg, rmsnorm(params["ln1"], h, cfg.norm_eps),
-                       pos, cache, ring=ring)
-    h = h + a
-    y, _ = moe_forward(params["moe"], cfg, rmsnorm(params["ln2"], h, cfg.norm_eps))
-    h = h + y
-    return h, kv
+    h, kv = _mla_decode(params, cfg, h, pos, cache, ring)
+    y, _, _ = moe_forward(params["moe"], cfg, rmsnorm(params["ln2"], h, cfg.norm_eps))
+    return h + y, kv
+
+
+def first_block_init(key, cfg: ArchConfig, dtype):
+    """A leading dense layer of a moe stack: MLA, then a SwiGLU of width d_ff."""
+    k1, k2 = jax.random.split(key)
+    return {
+        "ln1": rmsnorm_init(cfg.d_model, dtype),
+        "attn": mla_init(k1, cfg, dtype),
+        "ln2": rmsnorm_init(cfg.d_model, dtype),
+        "mlp": swiglu_init(k2, cfg.d_model, cfg.d_ff, dtype),
+    }
+
+
+def first_block_forward(params, cfg: ArchConfig, h, positions, *, window=0):
+    h, cache = _mla(params, cfg, h, positions, window)
+    return h + swiglu(params["mlp"], rmsnorm(params["ln2"], h, cfg.norm_eps)), cache
+
+
+def first_block_decode(params, cfg: ArchConfig, h, pos, cache, *, ring=False):
+    h, kv = _mla_decode(params, cfg, h, pos, cache, ring)
+    return h + swiglu(params["mlp"], rmsnorm(params["ln2"], h, cfg.norm_eps)), kv
 
 
 # -- ssm (Mamba2: mixer only, no separate MLP) --------------------------------

@@ -8,8 +8,11 @@ for 80-layer configs).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def dense_init(key, in_dim: int, out_dim: int, dtype, scale: float | None = None):
@@ -48,13 +51,42 @@ def rope_frequencies(dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, kind: str = "full"):
-    """x [..., T, n_heads, head_dim]; positions [..., T] (absolute)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention scaling 0.1 * mscale * ln(factor) + 1 (1 at factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, yarn) -> np.ndarray:
+    """DeepSeek-V2's YaRN inverse frequencies [dim/2] (float32).
+
+    Dimension pairs below the correction range keep the rope frequency
+    theta^(-2i/dim) (extrapolation), those above it take that frequency over
+    ``yarn.factor`` (interpolation), and a linear ramp blends the two in
+    between.  The range is [floor(d(beta_fast)), ceil(d(beta_slow))] with
+    d(r) = dim * ln(original_max / (2 pi r)) / (2 ln theta)."""
+    def corr(rot):
+        return dim * math.log(yarn.original_max / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low if high > low else 0.001), 0.0, 1.0)
+    return (extra / yarn.factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, kind: str = "full",
+               inv_freq=None):
+    """x [..., T, n_heads, head_dim]; positions [..., T] (absolute);
+    ``inv_freq`` [rot/2] replaces the plain rope frequencies (YaRN)."""
     if kind == "none":
         return x
     hd = x.shape[-1]
     rot_dim = hd if kind == "full" else hd // 2
-    freqs = rope_frequencies(rot_dim, theta)                        # [rot/2]
+    if inv_freq is None:
+        freqs = rope_frequencies(rot_dim, theta)                    # [rot/2]
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[..., :, None].astype(jnp.float32) * freqs       # [..., T, rot/2]
     cos = jnp.cos(ang)[..., :, None, :]                             # [..., T, 1, rot/2]
     sin = jnp.sin(ang)[..., :, None, :]

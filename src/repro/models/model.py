@@ -11,6 +11,8 @@ the serving path and the dry-run:
 
 Layers are stacked on a leading axis and scanned (compact HLO for 80-layer
 configs); ``cfg.remat == "full"`` wraps the per-layer body in jax.checkpoint.
+A moe stack's ``cfg.first_dense`` leading layers (MLA + dense FFN) are
+stacked under ``first`` and run before the ``blocks`` scan.
 """
 from __future__ import annotations
 
@@ -61,9 +63,26 @@ _INIT = {
 }
 
 
+def _zero_stats(cfg: ArchConfig):
+    """What the layer scan sums besides the hidden state: the auxiliary
+    loss, and for a moe stack also the rows its experts computed."""
+    if cfg.family == "moe":
+        return {"aux": jnp.float32(0.0), "moe_rows": jnp.int32(0)}
+    return jnp.float32(0.0)
+
+
+def _aux(stats):
+    return stats["aux"] if isinstance(stats, dict) else stats
+
+
 @dataclass(frozen=True)
 class Model:
     cfg: ArchConfig
+
+    def __post_init__(self):
+        if self.cfg.first_dense and self.cfg.family != "moe":
+            raise ValueError(f"first_dense is a moe stack's leading layers; "
+                             f"{self.cfg.name} is {self.cfg.family!r}")
 
     # ------------------------------------------------------------------ init
 
@@ -84,7 +103,11 @@ class Model:
             )
         else:
             p["blocks"] = jax.vmap(lambda k: init_one(k, cfg, dt))(
-                jax.random.split(keys[2], cfg.n_layers)
+                jax.random.split(keys[2], cfg.n_layers - cfg.first_dense)
+            )
+        if cfg.first_dense:
+            p["first"] = jax.vmap(lambda k: B.first_block_init(k, cfg, dt))(
+                jax.random.split(keys[7], cfg.first_dense)
             )
         p["final_norm"] = rmsnorm_init(cfg.d_model, dt)
         if not cfg.tie_embeddings:
@@ -98,9 +121,22 @@ class Model:
 
     # ------------------------------------------------------------- backbone
 
+    def _first(self, params, h, positions, window):
+        """The leading dense layers -> (h, their caches stacked)."""
+        cfg = self.cfg
+
+        def body(h, layer_params):
+            return B.first_block_forward(layer_params, cfg, h, positions, window=window)
+
+        if cfg.remat == "full":
+            body = jax.checkpoint(body)
+        return jax.lax.scan(body, h, params["first"])
+
     def _backbone(self, params, h, positions, *, collect_cache=False, window=0):
         cfg = self.cfg
         fwd = _FWD[cfg.family if cfg.family != "audio" else "dense"]
+        if cfg.first_dense:
+            h, first_caches = self._first(params, h, positions, window)
 
         def body(carry, layer_params):
             h, aux = carry
@@ -117,16 +153,26 @@ class Model:
                 from jax.sharding import PartitionSpec as _P
 
                 h = jax.lax.with_sharding_constraint(h, _P(None, "model", None))
-            return (h, aux + a), cache
+            return (h, jax.tree.map(jnp.add, aux, a)), cache
 
         if cfg.remat == "full":
             body = jax.checkpoint(body)
-        (h, aux), caches = jax.lax.scan(body, (h, jnp.float32(0.0)), params["blocks"],
+        (h, aux), caches = jax.lax.scan(body, (h, _zero_stats(cfg)), params["blocks"],
                                         unroll=cfg.scan_unroll)
+        if cfg.first_dense and collect_cache:
+            caches = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), first_caches, caches)
         return h, aux, caches
 
     def _decode_backbone(self, params, h, pos, cache_layers, *, ring=False, window=0):
         cfg = self.cfg
+        F = cfg.first_dense
+        if F:
+            def first_body(h, xs):
+                return B.first_block_decode(xs[0], cfg, h, pos, xs[1], ring=ring)
+
+            h, first_new = jax.lax.scan(
+                first_body, h, (params["first"], jax.tree.map(lambda c: c[:F], cache_layers)))
+            cache_layers = jax.tree.map(lambda c: c[F:], cache_layers)
 
         def body(h, xs):
             layer_params, layer_cache = xs
@@ -145,6 +191,8 @@ class Model:
 
         h, new_layers = jax.lax.scan(body, h, (params["blocks"], cache_layers),
                                      unroll=cfg.scan_unroll)
+        if F:
+            new_layers = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), first_new, new_layers)
         return h, new_layers
 
     def _logits(self, params, h):
@@ -175,14 +223,17 @@ class Model:
             h = h + sinusoid(positions, cfg.d_model)[None].astype(h.dtype)
 
         with jax.named_scope("blocks"):
-            h, aux, _ = self._backbone(params, h, positions, window=cfg.sliding_window)
+            h, stats, _ = self._backbone(params, h, positions, window=cfg.sliding_window)
         with jax.named_scope("lm_head"):
             h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
             h_text = h[:, offset:]
             logits = self._logits(params, h_text)
             ce = softmax_xent(logits, labels, onehot=cfg.opt_onehot_xent).mean()
+        aux = _aux(stats)
         loss = ce + aux
         metrics = {"ce": ce, "aux": aux}
+        if isinstance(stats, dict):
+            metrics["moe_rows"] = stats["moe_rows"]
 
         if cfg.mtp and S >= 2:
             # multi-token prediction: combine h_t with emb(x_{t+1}) -> predict x_{t+2}
@@ -191,7 +242,8 @@ class Model:
                 comb = jnp.concatenate([h_text[:, :-1], nxt], axis=-1) @ params["mtp_proj"]
                 pos2 = jnp.arange(S - 1)
                 fwd = _FWD[cfg.family]
-                hm, mtp_aux, _ = fwd(params["mtp_block"], cfg, comb, pos2, keep_cache=False)
+                hm, mtp_stats, _ = fwd(params["mtp_block"], cfg, comb, pos2, keep_cache=False)
+                mtp_aux = _aux(mtp_stats)
                 mtp_logits = self._logits(params, rmsnorm(params["final_norm"], hm, cfg.norm_eps))
                 mtp_ce = softmax_xent(mtp_logits, labels[:, 1:],
                                       onehot=cfg.opt_onehot_xent).mean()

@@ -120,3 +120,32 @@ def test_qwen_round_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES
+
+
+def test_dsv2lite_round_step_fits_one_chip(one_chip, monkeypatch):
+    """The benchmark's ``dsv2lite-xdev-equal`` round step (DeepSeek-V2-Lite
+    at its published widths, one chip's share: 1 dense + 8 MoE layers, 8 of
+    64 experts, 12,800 vocabulary rows; sequential cohort of 4, batch 2 x
+    512) compiles for the chip within the 15.75 GiB XLA may use of the 16 GiB
+    HBM, its routed experts through the grouped-matmul kernels, each under the
+    ``experts`` scope the benchmark reads.  The process's backend is the CPU,
+    so the model is told it lowers for the TPU (no interpret mode)."""
+    from bench import clients, harness, spec
+    from bench.weights import init_params
+    from repro.data.federated import FederatedPipeline
+    from repro.fed.rounds import as_device_batch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = spec.load_cell("dsv2lite-xdev-equal")
+    prog = harness.Program(cell)
+    s = cell.shape
+    state = jax.eval_shape(prog.strat.init, jax.eval_shape(lambda: init_params(s, 1)))
+    task = clients.TokenRows(s.vocab, cell.traffic["seq_len"], 1)
+    batch = as_device_batch(FederatedPipeline(task, prog.population, prog.fl).round_batch(0))
+    place = lambda tree: jax.tree.map(lambda x: _on(one_chip, x.shape, x.dtype), tree)
+    compiled = prog.step.lower(place(state), place(batch),
+                               _on(one_chip, (), jnp.float32)).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 9 and all("/moe/experts/" in k for k in kernels)
+    assert 0 < compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 1024**3

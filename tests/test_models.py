@@ -143,3 +143,26 @@ def test_param_counts_full_scale():
     assert 0.3e9 < totals["qwen1.5-0.5b"] < 0.8e9
     _, active = param_counts("deepseek-v3-671b")
     assert active < 0.15 * totals["deepseek-v3-671b"]  # ~37B active of 671B
+
+
+# sha256 of ``jax.jit(Model.loss).lower(...).as_text()`` for the tiny dense
+# configs, pinned before the leading dense layers, the MoE layer and YaRN came
+# in: the code they share with the dense stack (the layer scan, attention,
+# rope, the head) must still lower the dense program byte for byte.
+DENSE_LOSS_DIGESTS = {
+    ("qwen1.5-0.5b", "float32"): "e1f44badff034c2ba52ec9d663198b60b158a4bc83780a1a06eecf60e8fa3116",
+    ("qwen1.5-0.5b", "bfloat16"): "c056baaeefd5bb6fb7cb05e77d4e68c51e52948d02b79cc26c7a11aa18224c6c",
+    ("minicpm-2b", "float32"): "ebe27f2cb84ec6252a453e5203a0c8bcd581f8b4ba515beffb9f543d5687227c",
+    ("minicpm-2b", "bfloat16"): "9acc79cf9c38e314fff176b8fe3cacae95ef4938d947487e94877d794d1fd435",
+}
+
+
+@pytest.mark.parametrize("arch,dtype", sorted(DENSE_LOSS_DIGESTS))
+def test_dense_loss_lowers_to_the_pinned_program(arch, dtype):
+    import hashlib
+
+    model = build_model(ARCHS[arch].reduced(dtype=dtype))
+    params = jax.eval_shape(model.init, KEY)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 17), jnp.int32)}
+    text = jax.jit(model.loss).lower(params, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == DENSE_LOSS_DIGESTS[(arch, dtype)]
