@@ -16,6 +16,8 @@ deployment add it.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax.custom_batching import sequential_vmap
@@ -101,19 +103,24 @@ def moe_forward(params, cfg: ArchConfig, x):
         sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
         rows = jnp.sum(sizes)
         live = jnp.arange(R) < rows                                   # [R]
-        src = jnp.pad(order // k, (0, R - order.shape[0]))
+        pair = jnp.pad(order, (0, R - order.shape[0]))               # [R] each row's pair
+        # each held pair's row, the inverse of ``pair``: its group's first row
+        # plus the pairs of its group before it (0 for pairs held elsewhere)
+        mine = (group[:, None] == jnp.arange(n)).astype(jnp.int32)   # [N k, n]
+        before = jnp.cumsum(mine, axis=0) - mine + (jnp.cumsum(sizes) - sizes)
+        slot = jnp.sum(mine * before, axis=1)                        # [N k]
         # rows past the groups are never written by the grouped matmul: mask them
-        xs = jnp.where(live[:, None], tokens[src], 0)                # [R, D]
+        xs = _take_rows("moe_dispatch", tokens, pair // k, live,
+                        slot.reshape(N, k), held.reshape(N, k))      # [R, D]
 
     with jax.named_scope("experts"):
         h = jax.nn.silu(_grouped(xs, ex["gate"], sizes)) * _grouped(xs, ex["up"], sizes)
         ys = jnp.where(live[:, None], _grouped(h, ex["down"], sizes), 0)
 
     with jax.named_scope("moe_combine"):
-        slot = jnp.zeros((N * k,), jnp.int32).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
         w = jnp.where(held, gates.reshape(-1), 0.0).reshape(N, k)
-        picked = ys[slot].reshape(N, k, D)
+        picked = _take_rows("moe_combine", ys, slot, held,
+                            pair[:, None], live[:, None]).reshape(N, k, D)
         y = jnp.einsum("nk,nkd->nd", w, picked.astype(jnp.float32)).astype(x.dtype)
 
     y = y.reshape(B, T, D)
@@ -158,3 +165,28 @@ def _grouped_bwd(res, g):
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(scope, x, idx, ok, back, back_ok):
+    """Rows ``x[idx]`` [I, D] where ``ok``, else zero, for a one-to-one pairing
+    of rows that ``back`` [M, g] gives the other way round: each row of ``x``
+    went to at most ``g`` output rows, those of ``back`` where ``back_ok``.
+    The transpose is then a gather by ``back``, summed over ``g`` in float32
+    and run under the named scope ``scope``; XLA's own would sort the indices
+    and scatter-add rows, far below the HBM roof."""
+    return jnp.where(ok[:, None], x[idx], 0)
+
+
+def _take_rows_fwd(scope, x, idx, ok, back, back_ok):
+    return _take_rows(scope, x, idx, ok, back, back_ok), (back, back_ok)
+
+
+def _take_rows_bwd(scope, res, g):
+    back, back_ok = res
+    with jax.named_scope(scope):
+        d_x = jnp.where(back_ok[..., None], g[back].astype(jnp.float32), 0)
+        return jnp.sum(d_x, axis=1).astype(g.dtype), None, None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)

@@ -149,3 +149,33 @@ def test_dsv2lite_round_step_fits_one_chip(one_chip, monkeypatch):
                if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) == 9 and all("/moe/experts/" in k for k in kernels)
     assert 0 < compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 1024**3
+
+
+def test_expert_layer_moves_rows_by_gathers_only(one_chip, monkeypatch):
+    """One ``moe_forward`` layer, forward and backward, at the
+    ``dsv2lite-xdev-equal`` shapes (1024 tokens of width 2048, 8 of 64
+    experts of 1408, top-6, 2 shared): dispatch and combine and their
+    transposes move rows by gathers, so no scatter writes rows of width
+    d_model (XLA's own transpose of a row gather is a sorted scatter-add)."""
+    import re
+
+    from repro.configs.base import ArchConfig, MoEConfig
+    from repro.models.moe import moe_forward, moe_init
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    D = 2048
+    cfg = ArchConfig(name="dsv2lite-moe-layer", family="moe", d_model=D, dtype="bfloat16",
+                     moe=MoEConfig(num_experts=64, top_k=6, num_shared=2, expert_ff=1408,
+                                   held=8))
+    params = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), cfg, jnp.bfloat16))
+
+    def loss(p, x):
+        y, aux, _ = moe_forward(p, cfg, x)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    place = lambda tree: jax.tree.map(lambda a: _on(one_chip, a.shape, a.dtype), tree)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        place(params), _on(one_chip, (2, 512, D), jnp.bfloat16)).compile().as_text()
+    scatters = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", hlo)
+    assert [s for s in scatters if s.split(",")[-1] == str(D)] == []
+    assert re.search(rf"= bf16\[6144,{D}\]\S* gather\(", hlo)

@@ -108,21 +108,49 @@ def test_dropless_layer_equals_a_per_expert_loop(held, first_held, router_scale)
     assert int(rows) == n_held
 
 
+def _routed_here(p, first, held):
+    """p's router biased so that every token of a positive input chooses the
+    held experts ``first .. first + held - 1``."""
+    bias = jnp.zeros((D, E)).at[:, first:first + held].set(1.0)
+    return {**p, "router": p["router"] + bias}
+
+
 def test_every_token_routed_here_fits_the_row_bound():
     """A router that sends every token to the held experts routes the most
     rows a share can take, tokens * min(top_k, held), which the static row
     bound (that, up to whole row tiles) holds: nothing is dropped."""
     held, first = 2, 5
     cfg = _cfg(held=held, first_held=first)
-    p = _share(_uncut(), first, held)
-    bias = jnp.zeros((D, E)).at[:, first:first + held].set(1.0)
+    p = _routed_here(_share(_uncut(), first, held), first, held)
     x = jnp.abs(_x()) + 1.0
-    p = {**p, "router": p["router"] + bias}
     y, _, rows = moe_forward(p, cfg, x)
     want, n_held = _loop(p, cfg, x)
     assert int(rows) == n_held == 48 * held
     assert row_bound(cfg, 48) == 128 and row_bound(cfg, 64 * 48) == 48 * 64 * held
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("held,first_held,router_scale,routed_here", [
+    *((h, f, s, False) for h, f in [(0, 0), (4, 4), (2, 3), (1, 7)] for s in (1.0, 40.0)),
+    (2, 5, 1.0, True),
+])
+def test_layer_gradient_equals_a_per_expert_loops(held, first_held, router_scale,
+                                                  routed_here):
+    """On the same routing the layer's gradient, through its gather-only
+    dispatch and combine, equals the per-expert loop's for the routed and
+    shared experts, the router and the input; with every token routed here
+    (64 tokens, 2 held) the pairs fill all 128 static rows."""
+    cfg = _cfg(held=held, first_held=first_held)
+    p = _share(_uncut(router_scale=router_scale), first_held, cfg.moe.held_experts())
+    x = _x()
+    if routed_here:
+        p, x = _routed_here(p, first_held, held), jnp.abs(_x(T=32)) + 1.0
+        assert int(moe_forward(p, cfg, x)[2]) == row_bound(cfg, 64) == 128
+    cot = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(moe_forward(p, cfg, x)[0] * cot), (0, 1))(p, x)
+    want = jax.grad(lambda p, x: jnp.sum(_loop(p, cfg, x)[0] * cot), (0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):   # to each leaf's scale
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * max(1.0, float(jnp.max(jnp.abs(b)))))
 
 
 def test_balance_loss_is_deepseek_v2_sequence_wise():
