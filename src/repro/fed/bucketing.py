@@ -52,34 +52,28 @@ def _take_slots(extra, slots):
     return jax.tree.map(lambda t: jnp.take(t, slots, axis=0), extra)
 
 
-def vmap_clients(fn: Callable, batch: BucketedBatch, *per_slot):
-    """vmap ``fn(data_i, mask_i, *extras_i)`` over each bucket, reassemble.
-
-    ``per_slot`` are full-[C] arrays or pytrees with [C, ...] leaves (e.g.
-    the per-client step sizes, the gathered client-state rows); each bucket
-    sees its own view through ``Bucket.slots``.  Returns fn's output pytree
-    stacked in original [C, ...] slot order.
-    """
-    parts = [
-        jax.vmap(fn)(b.data, b.step_mask, *[_take_slots(a, b.slots) for a in per_slot])
-        for b in batch.buckets
-    ]
-    return unbucket(parts, batch.pos)
-
-
-def scan_clients(fn: Callable, batch: BucketedBatch, *per_slot):
-    """Like :func:`vmap_clients` but one ``lax.scan`` per bucket (sequential
-    cohort mode: each client still uses the whole mesh).  The per-bucket scan
-    stacks its outputs, so — unlike the padded sequential driver, which folds
-    the aggregation into its scan — this stages an O(sum_b C_b)-stacked
-    result tree before the (cheap) slot-order reduction replay.
-    """
-    def one_bucket(b):
-        def body(_, xs):
-            return None, fn(*xs)
-        _, ys = jax.lax.scan(
-            body, None,
-            (b.data, b.step_mask, *[_take_slots(a, b.slots) for a in per_slot]))
+def scan_stack(fn: Callable) -> Callable:
+    """``fn`` over a leading client axis by ``lax.scan``, outputs stacked:
+    the sequential counterpart of ``jax.vmap(fn)`` (each client still uses
+    the whole mesh)."""
+    def run(*xs):
+        _, ys = jax.lax.scan(lambda _, x: (None, fn(*x)), None, xs)
         return ys
 
-    return unbucket([one_bucket(b) for b in batch.buckets], batch.pos)
+    return run
+
+
+def stage_cohort(run: Callable, batch, *per_slot):
+    """``run(data, step_mask, *per_slot)`` over the cohort, in [C] slot order.
+
+    ``run`` is ``jax.vmap(fn)`` or ``scan_stack(fn)``.  ``per_slot`` are
+    full-[C] arrays or pytrees with [C, ...] leaves (the per-client step
+    sizes, the gathered client-state rows).  A padded batch is one call; a
+    bucketed one is one call per bucket on its slots' view of ``per_slot``,
+    reassembled to [C] by :func:`unbucket`.
+    """
+    if not isinstance(batch, BucketedBatch):
+        return run(batch.data, batch.step_mask, *per_slot)
+    parts = [run(b.data, b.step_mask, *[_take_slots(a, b.slots) for a in per_slot])
+             for b in batch.buckets]
+    return unbucket(parts, batch.pos)

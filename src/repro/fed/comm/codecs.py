@@ -328,6 +328,24 @@ def mbytes_per_slot(codec: Codec, params, valid) -> jnp.ndarray:
     return jnp.asarray(valid, jnp.float32) * jnp.float32(bits / 8e6)
 
 
+def wire_metrics(up: Codec | None, down: Codec | None, params, valid) -> dict:
+    """The round's bytes-on-wire keys for its compressed directions (``None``
+    for a dense one): each direction's megabytes and compression ratio, and
+    ``total_comm_mbytes`` for both, a dense direction charged its dense cost.
+    Static per client: every payload is params-shaped."""
+    dense = dense_bits(params)
+    out, bits = {}, {}
+    for direction, codec in (("uplink", up), ("downlink", down)):
+        if codec is not None:
+            bits[direction] = wire_bits_total(codec, params)
+            out[f"{direction}_mbytes"] = valid.sum() * jnp.float32(
+                bits[direction] / 8e6)
+            out[f"{direction}_compression"] = jnp.float32(dense / bits[direction])
+    out["total_comm_mbytes"] = valid.sum() * jnp.float32(
+        (bits.get("uplink", dense) + bits.get("downlink", dense)) / 8e6)
+    return out
+
+
 _DEPRECATION_WARNED: set[str] = set()
 
 

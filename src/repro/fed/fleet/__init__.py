@@ -16,8 +16,8 @@ adds no fleet math, the round step adds no metric keys — bitwise-frozen.
 from __future__ import annotations
 
 from ...configs.base import FLConfig
-from .buffered import (FLEET_STATE_KEY, fleet_client_state, slot_staleness,
-                       staleness_weights)
+from .buffered import (FLEET_STATE_KEY, count_arrivals, fleet_client_state,
+                       fleet_metrics, slot_staleness, staleness_weights)
 from .clock import BufferedSchedule, TickOutcome
 from .faults import (FAULTS, RoundFaults, apply_faults, register_fault,
                      validate_faults)
@@ -81,7 +81,8 @@ def validate_fleet_config(fl: FLConfig) -> None:
 
 __all__ = ["FLEETS", "FAULTS", "FLEET_STATE_KEY", "SERVER_MODES",
            "STALENESS_KINDS", "BufferedSchedule", "FleetModel", "RoundFaults",
-           "TickOutcome", "apply_faults", "build_fleet", "fleet_active",
-           "fleet_client_state", "fleet_uniform", "parse_faults",
+           "TickOutcome", "apply_faults", "build_fleet", "count_arrivals",
+           "fleet_active", "fleet_client_state", "fleet_metrics",
+           "fleet_uniform", "parse_faults",
            "register_fault", "register_fleet", "staleness_weights",
            "validate_faults", "validate_fleet_config"]

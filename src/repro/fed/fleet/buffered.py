@@ -63,3 +63,28 @@ def staleness_weights(fl: FLConfig, meta) -> jnp.ndarray:
     if fl.staleness == "constant":
         return jnp.ones_like(stal)
     return (1.0 + stal) ** jnp.float32(-fl.staleness_power)
+
+
+def count_arrivals(cs: dict, meta) -> dict:
+    """The cohort's fleet bank rows bumped for this round: one arrival each,
+    and its staleness added.  Applied before the round's masked bank commit,
+    so padding slots and dropped clients revert to what they read."""
+    stal = slot_staleness(meta)
+    fb = cs[FLEET_STATE_KEY]
+    return {**cs, FLEET_STATE_KEY: {"arrivals": fb["arrivals"] + 1.0,
+                                    "stale_sum": fb["stale_sum"] + stal}}
+
+
+def fleet_metrics(meta, valid_sum) -> dict:
+    """The fleet plane's round keys.  ``round_virtual_time`` is the slowest
+    surviving client's wall time in a sync round, and the tick's span in a
+    buffered one (its K-th arrival flushes it); ``valid_sum`` is the
+    cohort's clamped valid count, the mean's denominator."""
+    z = jnp.zeros_like(meta.valid)
+    stal = slot_staleness(meta)
+    arr = z if meta.arrive_time is None else jnp.asarray(meta.arrive_time, jnp.float32)
+    drp = z if meta.dropped is None else jnp.asarray(meta.dropped, jnp.float32)
+    return {"round_virtual_time": jnp.max(arr * meta.valid),
+            "arrived_clients": meta.valid.sum(),
+            "dropped_clients": drp.sum(),
+            "mean_staleness": (stal * meta.valid).sum() / valid_sum}

@@ -27,7 +27,7 @@ from .accountant import (DEFAULT_ORDERS, RDPAccountant, accountant_for,
                          check_dp_resume, dp_checkpoint_record,
                          rdp_subsampled_gaussian, sampling_rate)
 from .dp import (add_dp_noise, clip_update, dp_clip_cohort, dp_clip_transform,
-                 noise_key)
+                 dp_metrics, noise_key)
 from .secagg import (fixed_point_decode, fixed_point_encode, mask_matrix,
                      pair_keys, secagg_combine, secagg_payloads,
                      secagg_reference)
@@ -107,7 +107,7 @@ def validate_privacy_config(fl, *, transform_names: tuple = ()) -> None:
 __all__ = [
     "DEFAULT_ORDERS", "RDPAccountant", "accountant_for", "add_dp_noise",
     "check_dp_resume", "clip_update", "dp_active", "dp_checkpoint_record",
-    "dp_clip_cohort", "dp_clip_transform", "fixed_point_decode",
+    "dp_clip_cohort", "dp_clip_transform", "dp_metrics", "fixed_point_decode",
     "fixed_point_encode", "mask_matrix", "noise_key", "pair_keys",
     "privacy_active", "rdp_subsampled_gaussian", "sampling_rate",
     "secagg_active", "secagg_combine", "secagg_payloads", "secagg_reference",

@@ -143,3 +143,11 @@ def add_dp_noise(delta_agg, coeff, valid, fl, rnd):
         z = _std_normal(key_combine(key, jnp.uint32(i), jnp), leaf.shape)
         out.append((leaf.astype(jnp.float32) + sigma * z).astype(leaf.dtype))
     return jax.tree.unflatten(treedef, out), sigma
+
+
+def dp_metrics(clipped, sigma, valid, valid_sum) -> dict:
+    """The DP round keys: the share of valid clients the clip bit (the exact
+    indicator from :func:`dp_clip_cohort`, not a post-hoc norm test) and the
+    noise's sigma; ``valid_sum`` is the cohort's clamped valid count."""
+    return {"dp_clipped_frac": (clipped * valid).sum() / valid_sum,
+            "dp_sigma": sigma}

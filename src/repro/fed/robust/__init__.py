@@ -25,8 +25,9 @@ from .aggregators import (ROBUST_AGGS, TRIM_PARAM_AGGS, build_robust_aggregate,
 from .attacks import (ATTACKS, adversary_mask, attack_round_keys, build_attack,
                       register_attack)
 from .guards import (GUARDS, guard_quarantines, guard_rejects, params_ok,
-                     quarantine_masks, renormalize_coeffs, scrub_deltas,
-                     select_state, suspicion_ratio)
+                     quarantine, quarantine_masks, renormalize_coeffs,
+                     robust_metrics, scrub_deltas, select_state,
+                     suspicion_ratio)
 
 
 def robust_active(fl: FLConfig) -> bool:
@@ -65,7 +66,7 @@ def validate_robust_config(fl: FLConfig) -> None:
 __all__ = ["ATTACKS", "GUARDS", "ROBUST_AGGS", "TRIM_PARAM_AGGS",
            "adversary_mask", "attack_round_keys", "build_attack",
            "build_robust_aggregate", "guard_quarantines", "guard_rejects",
-           "params_ok", "quarantine_masks", "register_attack",
+           "params_ok", "quarantine", "quarantine_masks", "register_attack",
            "register_robust_agg", "renormalize_coeffs", "robust_active",
-           "scrub_deltas",
+           "robust_metrics", "scrub_deltas",
            "select_state", "suspicion_ratio", "validate_robust_config"]

@@ -112,6 +112,33 @@ def renormalize_coeffs(coeff, healthy) -> jnp.ndarray:
     return cf * healthy * scale
 
 
+def quarantine(deltas, coeff, meta, *, with_suspicion: bool):
+    """The quarantine guard over a decoded slot-order stack.
+
+    Returns ``(deltas, coeff, info)``: unhealthy slots' values zeroed, the
+    coefficients renormalized over the healthy ones, and the round's
+    ``quarantined_clients`` / ``suspected_adversaries`` counts (plus the
+    ``suspicion`` ratios, measured before the scrub, on request)."""
+    healthy, suspected = quarantine_masks(deltas, meta)
+    info = {"quarantined_clients": (meta.valid * (1.0 - healthy)).sum(),
+            "suspected_adversaries": suspected.sum()}
+    coeff = renormalize_coeffs(coeff, healthy)
+    if with_suspicion:
+        info["suspicion"] = suspicion_ratio(deltas, meta)
+    # zero the values too: a zeroed coefficient alone would still leak
+    # NaN/Inf through sorted-scan estimators (0 * nan = nan)
+    return scrub_deltas(deltas, healthy), coeff, info
+
+
+def robust_metrics(info: dict, rejected) -> dict:
+    """The robustness plane's round keys; a count reads 0 while its guard
+    is off (``rejected`` is None without the reject guard)."""
+    zero = jnp.float32(0.0)
+    return {"quarantined_clients": info.get("quarantined_clients", zero),
+            "suspected_adversaries": info.get("suspected_adversaries", zero),
+            "rounds_rejected": zero if rejected is None else rejected}
+
+
 def params_ok(prev_params, new_params) -> jnp.ndarray:
     """Scalar bool: the post-update parameters are finite and un-blown."""
     finite = jnp.array(True)

@@ -1,82 +1,25 @@
-"""The federated round step — a thin jit-able driver over a FedStrategy.
+"""The federated round step: one jit-able driver over a bound FedStrategy.
 
-``build_round_step(loss_fn, strategy, fl, num_clients)`` returns
+``build_round_step(loss_fn, strategy, fl, num_clients, *, plane=None)``
+returns ``round_step(state, batch, lr_mult) -> (state, metrics)``, a straight
+line of stages:
 
-    round_step(state: ServerState, batch: RoundBatch-as-jnp, lr_mult) ->
-        (ServerState, metrics)
+1. materialise: an engine ``IndexPlan`` becomes a batch on device (``plane``);
+2. broadcast: the cohort's rows of the client-state bank are gathered and,
+   under a compressed downlink, each slot's round-start params rebuilt;
+3. cohort: every client's local steps, ``vmapped`` or ``sequential``, over
+   a padded or bucketed batch, give slot-order ``[C]`` deltas and states;
+4. update path: DP clip -> attack -> uplink codec on that stack, then one
+   combine: quarantine -> robust aggregator, secagg, or the weighted sum;
+5. DP noise, the masked bank commit, the server update, the reject guard;
+6. metrics: three base keys and one call per active plane.
 
-The driver owns ONLY cohort execution; everything algorithm-specific (local
-step sizes, aggregation coefficients, server optimizer) comes from the bound
-strategy hooks (``repro.fed.strategy``).  Two cohort execution modes:
-
-* ``vmapped``    — clients of the cohort run in parallel (``jax.vmap``); on a
-  mesh the client axis is sharded over (pod, data) and each client's local
-  model replica occupies one model-parallel slice.  Cross-device FL layout.
-* ``sequential`` — ``lax.scan`` over the cohort; each client uses the whole
-  mesh (params FSDP+TP sharded) and the weighted delta is accumulated.
-  Cross-silo / huge-model layout (deepseek-v3 class).  A client run alone
-  computes its local steps only up to its last unmasked one (the local
-  loop's trip count is traced; ``core.local.scan_to_last_step``).
-
-Both modes compute *identical* math:
-    Delta = sum_i coeff_i * (y_i - x),   coeff_i = valid_i * w~_i / q_i^S
-    x    <- x + eta_g * Delta            (+ server optimizer state)
-with per-client local steps  y <- y - (eta_l / c_i) * g  (masked RR scan).
-
-When the bound strategy carries a non-identity uplink codec
-(``FLConfig.uplink``; ``repro.fed.comm``), each client's Delta_i passes
-through ``decode(encode(.))`` before aggregation — always vmapped over
-stacked slot-order [C] arrays (the compressed sequential-padded round stages
-its delta stack like the bucketed one), so codec float ops cannot be fused
-differently across layouts and padded == bucketed stays bitwise, error-
-feedback residuals and DIANA shifts (banked on ``ServerState.clients``
-under "uplink") included.  ``identity`` is an exact pass-through: the
-default path's op sequence is byte-for-byte the pre-uplink one.
-
-When the strategy also carries a non-identity *downlink* codec
-(``FLConfig.downlink``), the server's broadcast is compressed too: each
-cohort slot's round-start params become ``ref_i + decode(encode(x - ref_i))``
-against the client-held reference gathered from the bank (reserved key
-"downlink"), computed ONCE, vmapped over the slot-order [C] stack *before*
-the cohort executes — identical in every layout, so no extra staging is
-needed.  The reconstruction is committed back as the slot's next reference
-by the same masked O(cohort) scatter the other banks use (an unsampled
-client's reference goes stale but never desyncs), and each client's shipped
-update is measured from its own reconstruction (Q-NASTYA semantics).
-``downlink="identity"`` (the default) skips all of it — broadcast, client
-step and metric tree are byte-for-byte the pre-downlink ones.
-
-When the byzantine-robustness plane is active (``FLConfig.attack`` /
-``aggregator`` / ``guard``; ``repro.fed.robust``), the driver (1) lets the
-configured attack rewrite the stacked slot-order deltas *before* codec
-encode, (2) aggregates through the bound robust aggregator over explicit —
-and, after a quarantine, renormalized — coefficients, and (3) may
-where-select the previous ServerState when the reject guard trips.  The
-sequential-padded round stages its delta stack like the compressed one, so
-padded == bucketed stays bitwise; with the plane off (the default) none of
-this traces — the op sequence is byte-for-byte the pre-robustness one.
-
-When the privacy plane is active (``FLConfig.dp`` / ``secagg``;
-``repro.fed.privacy``), the driver (1) L2-clips each client's *shipped*
-update to ``dp_clip`` right after the local steps (before attacks and the
-codec — client-side semantics, bitwise-equal to the ``"dp_clip"``
-ClientTransform hook), (2) under ``secagg="pairwise"`` replaces the float
-weighted sum with the masked modular fixed-point aggregation (the codec
-roundtrip runs first: quantize-then-mask), and (3) under ``dp="on"`` adds
-counter-based per-(seed, round) Gaussian noise to the aggregate before the
-server update.  Off by default: the plane adds no ops and no metric keys —
-bitwise-frozen like comm/fleet/obs/robust.
-
-The step consumes either a materialized ``RoundBatch`` (legacy host
-assembly) or, when built with ``plane=`` (a cohort-engine
-:class:`~repro.fed.cohort.plane.DevicePlane`), an ``IndexPlan`` — indices
-and scalars only — which the plane materializes *inside* the jit by
-gathering the device-resident bank (and, for device RR backends,
-regenerating the reshuffling streams statelessly on device).
-
-Legacy call style ``build_round_step(loss_fn, fl, num_clients=...)`` still
-works: the FLConfig's ``algorithm``/``server_opt`` strings resolve through
-the strategy registry (see :func:`repro.fed.strategy.strategy_for`).
+Contracts.  Every cross-client step reads the same slot-order ``[C]`` stack
+in every layout, so padded == bucketed and legacy == engine hold bitwise.
+A sequential padded round with an empty update path (``stacked`` False)
+instead folds ``coeff_i * delta_i`` into its client scan by
+:func:`add_weighted`, the one accumulation rule, and never stacks the deltas.
+A plane that is off adds no op and no metric key.
 """
 from __future__ import annotations
 
@@ -91,33 +34,39 @@ from ..data.federated import Bucket, BucketedBatch, RoundBatch
 from ..obs import hist as obs_hist
 from ..obs import metrics_enabled, trace
 from ..utils.pytree import tree_zeros_like
-from .bucketing import scan_clients, vmap_clients
-from .comm import (DOWNLINK_STATE_KEY, UPLINK_STATE_KEY, dense_bits,
-                   downlink_apply, downlink_round_keys, mbytes_per_slot,
-                   round_keys, uplink_apply, wire_bits_total)
-from .fleet import FLEET_STATE_KEY, fleet_active, slot_staleness
-from .privacy import (add_dp_noise, dp_active, dp_clip_cohort, secagg_active,
-                      secagg_combine)
+from .bucketing import scan_stack, stage_cohort
+from .comm import (DOWNLINK_STATE_KEY, UPLINK_STATE_KEY, downlink_apply,
+                   downlink_round_keys, mbytes_per_slot, round_keys,
+                   uplink_apply, wire_metrics)
+from .fleet import (FLEET_STATE_KEY, count_arrivals, fleet_active,
+                    fleet_metrics, slot_staleness)
+from .privacy import (add_dp_noise, dp_active, dp_clip_cohort, dp_metrics,
+                      secagg_active, secagg_combine)
 from .robust import (build_attack, guard_quarantines, guard_rejects,
-                     params_ok, quarantine_masks, renormalize_coeffs,
-                     robust_active, scrub_deltas, select_state,
-                     suspicion_ratio)
+                     params_ok, quarantine, robust_active, robust_metrics,
+                     select_state, suspicion_ratio)
 from .server import ServerState
 from .strategy import (BoundStrategy, CohortState, FedStrategy, RoundCtx,
                        bind_strategy, weighted_sum)
 
 
+def add_weighted(acc, delta, coeff_i):
+    """``acc + coeff_i * delta`` in the accumulator's dtype: the sequential
+    cohort's accumulation rule, shared by its fused scan and its combine."""
+    with jax.named_scope("accumulate"):
+        return jax.tree.map(
+            lambda A, D: (A + coeff_i * D.astype(jnp.float32)).astype(A.dtype),
+            acc, delta,
+        )
+
+
 def build_round_step(loss_fn: Callable,
-                     strategy: "FedStrategy | BoundStrategy | FLConfig | None" = None,
+                     strategy: "FedStrategy | BoundStrategy | None" = None,
                      fl: FLConfig | None = None, num_clients: int | None = None,
                      *, plane=None) -> Callable:
     if isinstance(strategy, FLConfig):
-        # legacy signature build_round_step(loss_fn, fl[, num_clients])
-        if isinstance(fl, int) and num_clients is None:
-            num_clients = fl
-        elif fl is not None:
-            raise TypeError("pass either (strategy, fl) or the legacy (fl, num_clients)")
-        strategy, fl = None, strategy
+        raise TypeError("build_round_step takes the FLConfig as fl=...; its "
+                        "second argument is the strategy (or None)")
     if not isinstance(strategy, BoundStrategy):
         if fl is None:
             raise TypeError("build_round_step needs an FLConfig (fl=...)")
@@ -127,347 +76,225 @@ def build_round_step(loss_fn: Callable,
     # fl/num_clients given here agree with the config it was bound over
     strat = bind_strategy(strategy, fl, loss_fn, num_clients=num_clients)
     fl, num_clients = strat.fl, strat.num_clients
-    one_client = strat.local_step
-    # the [N+1, ...] client state bank carries stateful local-chain state
-    # AND the uplink codec's error-feedback residual (key "uplink")
-    banked = strat.client_state is not None
-    # uplink codec: clients encode their update in-jit, aggregation combines
-    # the DECODED updates on slot-order [C] arrays (identical padded /
-    # bucketed math); "identity" is an exact pass-through, so the default
-    # config's float op sequence is unchanged
-    codec = strat.codec
-    apply_up = uplink_apply(codec) if codec is not None else None
-    has_ef = codec is not None and codec.client_init is not None
-    # downlink broadcast codec: with a non-identity fl.downlink the server
-    # compresses the model delta against each slot's banked reference and the
-    # client starts the round from its reconstruction; identity (or a
-    # hand-built strategy, down_codec=None) broadcasts dense params — the
-    # pre-downlink op sequence exactly
-    down = strat.down_codec
+
+    # What is on, read once.  A codec of None (hand-built strategies only)
+    # is dense, like identity.
+    codec, down = strat.codec, strat.down_codec
+    up_on = codec is not None and codec.name != "identity"
     dl_on = down is not None and down.name != "identity"
-    apply_down = downlink_apply(down) if dl_on else None
-    # in-jit telemetry histograms (fl.telemetry): fixed-shape summaries over
-    # the slot-order [C] arrays every path already stages, with static
-    # config-derived edges (obs.hist cardinality contract).  "off" (the
-    # default) adds no ops and no metric keys — bitwise-frozen.
-    tele_hist = metrics_enabled(fl.telemetry)
-    # byzantine-robustness plane (fed.robust): attacks rewrite the stacked
-    # slot-order deltas BEFORE the uplink codec (adversaries control their
-    # wire payload), robust aggregators / quarantine combine over explicit
-    # renormalizable coefficients, and the reject guard where-selects the
-    # previous state on post-update blowup.  All off by default: the plane
-    # adds no ops and no metric keys — bitwise-frozen like comm/fleet/obs.
-    robust_on = robust_active(fl)
-    apply_attack = build_attack(fl) if robust_on else None
+    robust_on, dp_on, sa_on = robust_active(fl), dp_active(fl), secagg_active(fl)
+    fleet_on = fleet_active(fl)
     g_quar = robust_on and guard_quarantines(fl)
     g_rej = robust_on and guard_rejects(fl)
-    # privacy plane (fed.privacy): per-client DP clipping runs on the staged
-    # slot-order stack right after the local steps (before attacks/codec —
-    # client-side semantics), secagg replaces the float weighted sum with
-    # the masked modular aggregation, DP noise lands on the aggregate.  Off
-    # by default: no new ops, no new metric keys — bitwise-frozen.
-    dp_on = dp_active(fl)
-    sa_on = secagg_active(fl)
+    tele_hist = metrics_enabled(fl.telemetry)
+    banked = strat.client_state is not None
+    sequential = fl.cohort_mode == "sequential"
+    # the update path runs on the slot-order [C] stack of the cohort's deltas
+    stacked = up_on or robust_on or dp_on or sa_on
+    apply_up = uplink_apply(codec) if up_on else None
+    apply_down = downlink_apply(down) if dl_on else None
+    attack = build_attack(fl) if robust_on else None
     hist_edges = obs_hist.round_hist_edges(
-        fl, with_staleness=fleet_active(fl),
-        with_uplink=codec is not None and codec.name != "identity",
-        with_robust=robust_on, with_dp=dp_on, with_downlink=dl_on,
-    ) if tele_hist else {}
+        fl, with_staleness=fleet_on, with_uplink=up_on, with_robust=robust_on,
+        with_dp=dp_on, with_downlink=dl_on) if tele_hist else {}
+
+    def materialize(batch):
+        if isinstance(batch, (RoundBatch, BucketedBatch)):
+            return batch
+        # cohort-engine path: an IndexPlan / BucketedPlan gathered through
+        # the resident bank (device RR backends regenerate the index streams
+        # here, inside the jit)
+        if plane is None:
+            raise TypeError(
+                "round_step received an index plan but build_round_step was "
+                "called without plane=; pass the engine's DevicePlane")
+        return plane.materialize(batch)
+
+    def gather_bank(state, meta):
+        """(bank row per slot, the cohort's gathered rows).  Invalid padding
+        slots read, and later write, the scratch row, so a round's state
+        traffic is O(cohort) whatever the population."""
+        if not banked:
+            return None, {}
+        if state.clients is None:
+            raise TypeError(
+                f"round_step for local update {strat.local_update!r} / "
+                f"uplink codec {codec.name if codec else None!r} got a "
+                f"ServerState without a client state bank; build the "
+                f"state with the bound strategy's init() (legacy "
+                f"init_server predates stateful chains / error-feedback "
+                f"codecs and keeps none).")
+        with jax.named_scope("bank_gather"):
+            ids = jnp.where(meta.valid > 0, meta.client_id,
+                            num_clients).astype(jnp.int32)
+            return ids, jax.tree.map(lambda b: jnp.take(b, ids, axis=0),
+                                     state.clients)
+
+    def broadcast(state, meta, cstate0):
+        """The cohort state the clients start from.  Under a compressed
+        downlink each slot's round-start params are rebuilt from its banked
+        reference, vmapped over the slot-order stack before the cohort runs,
+        and ride the "downlink" key: the client starts (and measures its
+        update) from them, and the bank commit makes them the slot's next
+        reference.  ``cstate0`` stays the gathered rows, what invalid slots
+        revert to."""
+        if not dl_on:
+            return cstate0
+        with jax.named_scope("downlink"):
+            if down.seeded:
+                dkeys = downlink_round_keys(fl.seed, meta.client_id, state.rnd, jnp)
+            else:
+                dkeys = jnp.zeros(meta.valid.shape, jnp.uint32)
+            params_hat = jax.vmap(apply_down, in_axes=(None, 0, 0))(
+                state.params, cstate0[DOWNLINK_STATE_KEY]["ref"], dkeys)
+        return {**cstate0, DOWNLINK_STATE_KEY: {"ref": params_hat}}
+
+    def fused_cohort(client, batch, eta, coeff, acc0, cs):
+        """The sequential padded cohort with an empty update path: one scan
+        that folds each client's weighted delta into ``acc0`` as it goes."""
+        def body(acc, xs):
+            data_i, mask_i, eta_i, coeff_i, cs_i = xs
+            delta, loss, cs_new = client(data_i, mask_i, eta_i, cs_i)
+            ys = (loss, cs_new)
+            if tele_hist:
+                ys = ys + (obs_hist.tree_sqnorm(delta),)
+            return add_weighted(acc, delta, coeff_i), ys
+
+        delta_agg, ys = jax.lax.scan(
+            body, acc0, (batch.data, batch.step_mask, eta, coeff, cs))
+        losses, new_cs, *slot_sq = ys
+        return delta_agg, losses, new_cs, slot_sq[0] if tele_hist else None
+
+    def update_path(deltas, new_cs, meta, rnd, keys):
+        """DP clip -> attack -> uplink codec on the slot-order stack; the
+        codec's error-feedback residuals commit into the cohort state."""
+        info = {}
+        with jax.named_scope("update_path"):
+            if dp_on:
+                # client side, the exact sensitivity bound; before attacks,
+                # whose adversaries are not assumed to honour it
+                deltas, info["dp_clipped"], info["dp_scale"] = dp_clip_cohort(deltas, fl)
+            if attack is not None:
+                # before encode: adversaries control their wire payload
+                deltas = attack(deltas, meta, rnd)
+            if up_on:
+                deltas, ef = jax.vmap(apply_up)(
+                    deltas, new_cs.get(UPLINK_STATE_KEY, {}), keys)
+                if codec.client_init is not None:
+                    new_cs = {**new_cs, UPLINK_STATE_KEY: ef}
+        return deltas, new_cs, info
+
+    def combine(deltas, coeff, acc0, meta, rnd):
+        """Quarantine -> the robust aggregator, secagg, or the weighted sum
+        (``strat.aggregate`` for a vmapped cohort, the ``add_weighted`` scan
+        for a sequential one).  Returns (delta_agg, info)."""
+        info = {}
+        with jax.named_scope("update_path"):
+            if g_quar:
+                deltas, coeff, info = quarantine(
+                    deltas, coeff, meta, with_suspicion="hist_suspicion" in hist_edges)
+            elif "hist_suspicion" in hist_edges:
+                info["suspicion"] = suspicion_ratio(deltas, meta)
+            if sa_on:
+                # the server sees only the masked modular sum; validation
+                # pins aggregator="mean" and forbids quarantine here
+                return secagg_combine(deltas, coeff, meta.valid, meta.dropped,
+                                      meta.client_id, rnd, fl), info
+            if robust_on:
+                if strat.robust_aggregate is None:
+                    return weighted_sum(deltas, coeff), info
+                return strat.robust_aggregate(deltas, coeff, meta), info
+        if not sequential:
+            with jax.named_scope("accumulate"):
+                return strat.aggregate(deltas, meta), info
+        delta_agg, _ = jax.lax.scan(
+            lambda acc, xs: (add_weighted(acc, *xs), None), acc0, (deltas, coeff))
+        return delta_agg, info
+
+    def commit_bank(state, meta, ids, cstate0, new_cs):
+        """(CohortState, new bank): invalid slots commit exactly what they
+        read, then every slot scatters back to its own row in slot order."""
+        if not banked:
+            return None, None
+        if FLEET_STATE_KEY in new_cs:
+            new_cs = count_arrivals(new_cs, meta)
+        valid = meta.valid
+        with jax.named_scope("bank_scatter"):
+            upd = jax.tree.map(
+                lambda n, o: jnp.where(
+                    (valid > 0).reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+                new_cs, cstate0)
+            return CohortState(old=cstate0, new=upd), jax.tree.map(
+                lambda b, u: b.at[ids].set(u.astype(b.dtype)), state.clients, upd)
 
     def round_step(state: ServerState, batch, lr_mult=1.0):
-        if not isinstance(batch, (RoundBatch, BucketedBatch)):
-            # cohort-engine path: an IndexPlan / BucketedPlan — materialize on
-            # device (gather through the resident bank; device RR backends
-            # also regenerate the index streams here, inside the jit)
-            if plane is None:
-                raise TypeError(
-                    "round_step received an index plan but build_round_step was "
-                    "called without plane=; pass the engine's DevicePlane")
-            batch = plane.materialize(batch)
-        bucketed = isinstance(batch, BucketedBatch)
+        batch = materialize(batch)
         meta = batch.meta
-        # the reject guard reverts to the round's input state — capture it
-        # before anything rebinds ``state`` (safe under donation: reads of
-        # the donated buffers happen inside this jit, before release)
-        prev_state = state if g_rej else None
+        prev_state = state      # what the reject guard reverts to
         with jax.named_scope("client_transform"):
             plan = strat.client_transform(meta, lr_mult)               # eta [C]
         momentum = state.opt.get("m", None)
         if momentum is None:
             momentum = tree_zeros_like(state.params)
-        if banked:
-            if state.clients is None:
-                raise TypeError(
-                    f"round_step for local update {strat.local_update!r} / "
-                    f"uplink codec {codec.name if codec else None!r} got a "
-                    f"ServerState without a client state bank; build the "
-                    f"state with the bound strategy's init() (legacy "
-                    f"init_server predates stateful chains / error-feedback "
-                    f"codecs and keeps none).")
-            # gather the cohort's rows of the per-client state bank (invalid
-            # padding slots read — and later write — the scratch row, so a
-            # round's state traffic is O(cohort) regardless of population)
-            with jax.named_scope("bank_gather"):
-                ids = jnp.where(meta.valid > 0, meta.client_id,
-                                num_clients).astype(jnp.int32)
-                cstate0 = jax.tree.map(lambda b: jnp.take(b, ids, axis=0),
-                                       state.clients)
-        else:
-            cstate0 = {}
-
-        # downlink broadcast: reconstruct each slot's round-start params from
-        # its banked reference ONCE, vmapped over the slot-order [C] stack,
-        # BEFORE the cohort executes — identical float ops in every layout.
-        # The reconstruction rides the cohort state under the "downlink" key:
-        # the untouched pass-through in one_client carries it to new_cs, and
-        # the masked bank commit below makes it the slot's next reference.
-        # cstate0 stays the GATHERED rows — invalid slots must revert to what
-        # they read (every padding slot aims at the scratch row, and their
-        # writes must agree), not to a per-slot reconstruction.
-        cstate_in = cstate0
-        if dl_on:
-            with jax.named_scope("downlink"):
-                if down.seeded:
-                    dkeys = downlink_round_keys(fl.seed, meta.client_id,
-                                                state.rnd, jnp)
-                else:
-                    dkeys = jnp.zeros(meta.valid.shape, jnp.uint32)
-                params_hat = jax.vmap(apply_down, in_axes=(None, 0, 0))(
-                    state.params, cstate0[DOWNLINK_STATE_KEY]["ref"], dkeys)
-            cstate_in = {**cstate0, DOWNLINK_STATE_KEY: {"ref": params_hat}}
+        ids, cstate0 = gather_bank(state, meta)
+        cstate_in = broadcast(state, meta, cstate0)
 
         def client(data_i, mask_i, eta_i, cs_i):
-            # with the downlink compressed, the client's round-start point is
-            # its own reconstruction (its update is measured from there too)
             p_i = cs_i[DOWNLINK_STATE_KEY]["ref"] if dl_on else state.params
-            return one_client(p_i, momentum, state.opt,
-                              data_i, mask_i, eta_i, cs_i)
+            return strat.local_step(p_i, momentum, state.opt,
+                                    data_i, mask_i, eta_i, cs_i)
 
-        # per-client uplink stream keys (seed, client, round) — only codecs
-        # with sampling randomness consume them; keyed off the absolute round
-        # counter so a checkpoint resume replays identical streams
-        if apply_up is not None and codec.seeded:
+        # per-client uplink stream keys (seed, client, round): the absolute
+        # round counter makes a resumed run replay the same streams
+        if up_on and codec.seeded:
             keys = round_keys(fl.seed, meta.client_id, state.rnd, jnp)
         else:
             keys = jnp.zeros(meta.valid.shape, jnp.uint32)
-
-        def uplink_cohort(deltas, new_cs):
-            """Encode+decode the cohort's stacked slot-order deltas; commit
-            new error-feedback residuals into the cohort state."""
-            if apply_up is None:
-                return deltas, new_cs
-            dhat, ef2 = jax.vmap(apply_up)(
-                deltas, new_cs.get(UPLINK_STATE_KEY, {}), keys)
-            if has_ef:
-                new_cs = {**new_cs, UPLINK_STATE_KEY: ef2}
-            return dhat, new_cs
-
-        def secagg_agg(deltas, coeff):
-            """Masked modular fixed-point aggregation (fed.privacy.secagg):
-            pairwise masks cancel exactly, dropped clients' shares recovered."""
-            with jax.named_scope("update_path"):
-                return secagg_combine(deltas, coeff, meta.valid, meta.dropped,
-                                      meta.client_id, state.rnd, fl)
-
-        def robust_combine(deltas):
-            """Aggregate the decoded slot-order stack under the robustness
-            plane: quarantine -> coefficient renormalization -> the bound
-            robust aggregator (``mean`` == the canonical weighted_sum)."""
-            coeff = strat.agg_coeffs(meta)                           # [C]
-            info = {"quarantined_clients": jnp.float32(0.0),
-                    "suspected_adversaries": jnp.float32(0.0)}
-            if g_quar:
-                healthy, suspected = quarantine_masks(deltas, meta)
-                info["quarantined_clients"] = (meta.valid * (1.0 - healthy)).sum()
-                info["suspected_adversaries"] = suspected.sum()
-                coeff = renormalize_coeffs(coeff, healthy)
-                if "hist_suspicion" in hist_edges:
-                    info["suspicion"] = suspicion_ratio(deltas, meta)
-                # zero the quarantined slots' values too: a zeroed
-                # coefficient alone would still leak NaN/Inf through
-                # sorted-scan estimators (0 * nan = nan)
-                deltas = scrub_deltas(deltas, healthy)
-            elif "hist_suspicion" in hist_edges:
-                info["suspicion"] = suspicion_ratio(deltas, meta)
-            combine = strat.robust_aggregate
-            if sa_on:
-                # robust plane limited to attack / reject here — validation
-                # pins aggregator="mean" and forbids quarantine under secagg
-                # (the server only ever sees the blinded sum)
-                return secagg_agg(deltas, coeff), info
-            if combine is None:       # hand-built strategy: canonical mean
-                return weighted_sum(deltas, coeff), info
-            return combine(deltas, coeff, meta), info
-
-        rb_info = None
-        slot_sq = None  # [C] squared update norms, only under telemetry
-        dp_clipped = dp_scale = dp_sigma = None  # privacy-plane telemetry
-        if fl.cohort_mode == "vmapped":
-            if bucketed:
-                # per-bucket [C_b, K_b] scans, reassembled to [C] slot order
-                # before any cross-client math — bitwise-identical aggregate
-                deltas, losses, new_cs = vmap_clients(client, batch, plan.eta,
-                                                      cstate_in)
-            else:
-                deltas, losses, new_cs = jax.vmap(client)(
-                    batch.data, batch.step_mask, plan.eta, cstate_in)
-            with jax.named_scope("update_path"):
-                if dp_on:
-                    # client-side DP clipping of the shipped update (the exact
-                    # sensitivity bound) — before attacks: adversaries are not
-                    # assumed to honor it (that is the robust plane's problem)
-                    deltas, dp_clipped, dp_scale = dp_clip_cohort(deltas, fl)
-                if apply_attack is not None:
-                    # before encode: adversaries control their wire payload
-                    deltas = apply_attack(deltas, meta, state.rnd)
-                deltas, new_cs = uplink_cohort(deltas, new_cs)
-            if tele_hist:
-                slot_sq = obs_hist.slot_sqnorms(deltas)
-            if robust_on:
-                with jax.named_scope("update_path"):
-                    delta_agg, rb_info = robust_combine(deltas)
-            elif sa_on:
-                delta_agg = secagg_agg(deltas, strat.agg_coeffs(meta))
-            else:
-                with jax.named_scope("accumulate"):
-                    delta_agg = strat.aggregate(deltas, meta)
-        else:  # sequential: the scan accumulates coeff_i * Delta_i as it goes,
-            # so the strategy contributes through agg_coeffs rather than the
-            # whole-cohort aggregate hook
-            with jax.named_scope("agg_coeffs"):
-                coeff = strat.agg_coeffs(meta)                         # [C]
-            acc_dt = jnp.dtype(fl.accum_dtype)
+        with jax.named_scope("agg_coeffs"):
+            coeff = strat.agg_coeffs(meta)                             # [C]
+        acc0 = None
+        if sequential:
             with jax.named_scope("accumulate"):
-                acc0 = jax.tree.map(lambda x: jnp.zeros_like(x, acc_dt), state.params)
+                acc0 = jax.tree.map(
+                    lambda x: jnp.zeros_like(x, jnp.dtype(fl.accum_dtype)),
+                    state.params)
 
-            def add_weighted(acc, delta, coeff_i):
-                # THE accumulation rule — one definition, shared by the fused
-                # and the staged paths (the bitwise contract between them)
-                with jax.named_scope("accumulate"):
-                    return jax.tree.map(
-                        lambda A, D: (A + coeff_i * D.astype(jnp.float32)).astype(A.dtype),
-                        acc, delta,
-                    )
-
-            deltas = None
-            if bucketed:
-                # per-bucket client scans stage stacked deltas, then the same
-                # coeff_i-weighted accumulation replays in slot order
-                deltas, losses, new_cs = scan_clients(client, batch, plan.eta,
-                                                      cstate_in)
-            elif ((apply_up is not None and codec.name != "identity")
-                  or robust_on or dp_on or sa_on):
-                # compressed uplink / robustness / privacy planes: stage the
-                # per-client deltas (scan) so the codec, attacks, robust
-                # aggregators, DP clip and secagg masks run vmapped on the
-                # stacked [C] slot-order arrays, like every other layout.
-                # Applying them inside the fused scan body instead would let
-                # XLA contract their float ops differently there (FMA
-                # fusion), silently breaking the padded == bucketed bitwise
-                # contract (error-feedback residuals, cross-client
-                # estimators).
-                def stage(_, xs):
-                    return None, client(*xs)
-
-                _, (deltas, losses, new_cs) = jax.lax.scan(
-                    stage, None,
-                    (batch.data, batch.step_mask, plan.eta, cstate_in))
-
-            if deltas is not None:
-                with jax.named_scope("update_path"):
-                    if dp_on:
-                        # same client-side clip as the vmapped path (slot order)
-                        deltas, dp_clipped, dp_scale = dp_clip_cohort(deltas, fl)
-                    if apply_attack is not None:
-                        deltas = apply_attack(deltas, meta, state.rnd)
-                    deltas, new_cs = uplink_cohort(deltas, new_cs)
-                if tele_hist:
-                    slot_sq = obs_hist.slot_sqnorms(deltas)
-
-                if robust_on:
-                    with jax.named_scope("update_path"):
-                        delta_agg, rb_info = robust_combine(deltas)
-                elif sa_on:
-                    delta_agg = secagg_agg(deltas, coeff)
-                else:
-                    def accum(acc, xs):
-                        delta, coeff_i = xs
-                        return add_weighted(acc, delta, coeff_i), None
-
-                    delta_agg, _ = jax.lax.scan(accum, acc0, (deltas, coeff))
-            else:
-                def body(acc, xs):
-                    data_i, mask_i, eta_i, coeff_i, cs_i = xs
-                    delta, loss, cs_new = client(data_i, mask_i, eta_i, cs_i)
-                    ys = (loss, cs_new)
-                    if tele_hist:
-                        # telemetry extends the scan ys; the off path's body
-                        # is literally the pre-telemetry one
-                        ys = ys + (obs_hist.tree_sqnorm(delta),)
-                    return add_weighted(acc, delta, coeff_i), ys
-
-                delta_agg, ys = jax.lax.scan(
-                    body, acc0,
-                    (batch.data, batch.step_mask, plan.eta, coeff, cstate_in)
-                )
-                if tele_hist:
-                    losses, new_cs, slot_sq = ys
-                else:
-                    losses, new_cs = ys
+        info = {}
+        if sequential and not stacked and not isinstance(batch, BucketedBatch):
+            delta_agg, losses, new_cs, slot_sq = fused_cohort(
+                client, batch, plan.eta, coeff, acc0, cstate_in)
+        else:
+            run = scan_stack(client) if sequential else jax.vmap(client)
+            deltas, losses, new_cs = stage_cohort(run, batch, plan.eta, cstate_in)
+            deltas, new_cs, info = update_path(deltas, new_cs, meta, state.rnd, keys)
+            slot_sq = obs_hist.slot_sqnorms(deltas) if tele_hist else None
+            delta_agg, combine_info = combine(deltas, coeff, acc0, meta, state.rnd)
+            info.update(combine_info)
+        if sequential:
             with jax.named_scope("accumulate"):
                 delta_agg = jax.tree.map(lambda a, p: a.astype(p.dtype), delta_agg,
                                          state.params)
-
         if dp_on:
-            # counter-based per-(seed, round) Gaussian noise on the weighted
-            # aggregate — identical wherever the round is produced (legacy /
-            # engine / prefetch / resume), mode-independent by construction
+            # counter-based per-(seed, round) Gaussian noise on the aggregate:
+            # the same wherever and however the round is produced
             with jax.named_scope("update_path"):
-                delta_agg, dp_sigma = add_dp_noise(
-                    delta_agg, strat.agg_coeffs(meta), meta.valid, fl, state.rnd)
+                delta_agg, info["dp_sigma"] = add_dp_noise(
+                    delta_agg, coeff, meta.valid, fl, state.rnd)
 
-        cstate = None
-        new_clients = None
-        if banked and FLEET_STATE_KEY in new_cs:
-            # buffered server bookkeeping: bump the cohort's arrival /
-            # staleness counters BEFORE the masked commit below, so invalid
-            # padding slots (and dropped clients) revert to what they read
-            fb = new_cs[FLEET_STATE_KEY]
-            stal = slot_staleness(meta)
-            new_cs = {**new_cs, FLEET_STATE_KEY: {
-                "arrivals": fb["arrivals"] + 1.0,
-                "stale_sum": fb["stale_sum"] + stal,
-            }}
-        if banked:
-            # invalid slots commit exactly what they read (layout-independent
-            # — the bucketed reassembly's zeros row never reaches the bank),
-            # then every slot scatters back to its own bank row in slot order
-            valid = meta.valid
-            with jax.named_scope("bank_scatter"):
-                upd = jax.tree.map(
-                    lambda n, o: jnp.where(
-                        (valid > 0).reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
-                    new_cs, cstate0)
-                cstate = CohortState(old=cstate0, new=upd)
-                new_clients = jax.tree.map(
-                    lambda b, u: b.at[ids].set(u.astype(b.dtype)),
-                    state.clients, upd)
-
+        cstate, new_clients = commit_bank(state, meta, ids, cstate0, new_cs)
         ctx = RoundCtx(batch=batch, lr_mult=lr_mult, momentum=momentum,
                        cstate=cstate)
         with jax.named_scope("server_update"):
             state = strat.server_update(state, delta_agg,
                                         jnp.asarray(fl.server_lr, jnp.float32), ctx)
         if new_clients is not None:
-            # server opts construct ServerState(params=, opt=, rnd=) — the
-            # driver owns the bank and re-attaches the scattered update
+            # server opts build ServerState(params=, opt=, rnd=); the driver
+            # owns the bank and re-attaches the scattered update
             state = state._replace(clients=new_clients)
-
         rejected = None
         if g_rej:
-            # divergence guard: a blown round's param/opt/bank updates are
-            # discarded in-jit; the round counter still advances (a rejected
-            # round is skipped, not replayed — schedules/keys stay aligned)
+            # a blown round's updates are discarded in-jit; the round counter
+            # still advances (skipped, not replayed)
             ok = params_ok(prev_state.params, state.params)
             state = select_state(ok, state, prev_state)
             rejected = 1.0 - ok.astype(jnp.float32)
@@ -480,90 +307,31 @@ def build_round_step(loss_fn: Callable,
             ),
             "cohort": meta.valid.sum(),
         }
-        up_on = codec is not None and codec.name != "identity"
-        if up_on:
-            # bytes-on-wire accounting (static per client — every update is
-            # model-shaped); identity adds no keys so the default metric tree
-            # stays frozen
-            bits_pc = wire_bits_total(codec, state.params)
-            metrics["uplink_mbytes"] = meta.valid.sum() * jnp.float32(
-                bits_pc / 8e6)
-            metrics["uplink_compression"] = jnp.float32(
-                dense_bits(state.params) / bits_pc)
-        if dl_on:
-            # the broadcast's side of the ledger, same static accounting
-            dbits_pc = wire_bits_total(down, state.params)
-            metrics["downlink_mbytes"] = meta.valid.sum() * jnp.float32(
-                dbits_pc / 8e6)
-            metrics["downlink_compression"] = jnp.float32(
-                dense_bits(state.params) / dbits_pc)
         if up_on or dl_on:
-            # both directions of the wire in one number; an identity (or
-            # absent) direction is charged its honest dense cost
-            ub = bits_pc if up_on else dense_bits(state.params)
-            db = dbits_pc if dl_on else dense_bits(state.params)
-            metrics["total_comm_mbytes"] = meta.valid.sum() * jnp.float32(
-                (ub + db) / 8e6)
-        if fleet_active(fl):
-            # fleet telemetry — keys exist only when the fleet plane is on,
-            # so every pre-existing configuration's metric tree stays frozen.
-            # round_virtual_time: sync = slowest surviving client's wall
-            # time; buffered = the tick's span (the K-th arrival flushes it).
-            z = jnp.zeros_like(meta.valid)
-            stal = slot_staleness(meta)
-            arr = z if meta.arrive_time is None else jnp.asarray(meta.arrive_time, jnp.float32)
-            drp = z if meta.dropped is None else jnp.asarray(meta.dropped, jnp.float32)
-            metrics["round_virtual_time"] = jnp.max(arr * meta.valid)
-            metrics["arrived_clients"] = meta.valid.sum()
-            metrics["dropped_clients"] = drp.sum()
-            metrics["mean_staleness"] = (stal * meta.valid).sum() / valid_sum
+            metrics.update(wire_metrics(codec if up_on else None,
+                                        down if dl_on else None,
+                                        state.params, meta.valid))
+        if fleet_on:
+            metrics.update(fleet_metrics(meta, valid_sum))
         if robust_on:
-            # robustness telemetry — keys exist only while the plane is on
-            # (same metric-tree freeze as the fleet/uplink keys above); the
-            # counts are 0 whenever the corresponding guard is not active
-            metrics["quarantined_clients"] = rb_info["quarantined_clients"]
-            metrics["suspected_adversaries"] = rb_info["suspected_adversaries"]
-            metrics["rounds_rejected"] = (jnp.float32(0.0) if rejected is None
-                                          else rejected)
+            metrics.update(robust_metrics(info, rejected))
         if dp_on:
-            # privacy telemetry — keys exist only while DP is on (same
-            # metric-tree freeze as the other planes); clipped_frac is the
-            # exact indicator from the clip itself, not a post-hoc norm test
-            metrics["dp_clipped_frac"] = (dp_clipped * meta.valid).sum() / valid_sum
-            metrics["dp_sigma"] = dp_sigma
+            metrics.update(dp_metrics(info["dp_clipped"], info["dp_sigma"],
+                                      meta.valid, valid_sum))
         if tele_hist:
-            # fixed-shape distribution summaries (obs.hist): hist_*-prefixed
-            # [bins] counts — the train loop routes them to registry
-            # Histogram instruments rather than the scalar metric row
-            metrics["hist_steps"] = obs_hist.fixed_histogram(
-                meta.num_steps, hist_edges["hist_steps"], weights=meta.valid)
-            metrics["hist_update_norm"] = obs_hist.fixed_histogram(
-                jnp.sqrt(slot_sq), hist_edges["hist_update_norm"],
-                weights=meta.valid)
-            if "hist_staleness" in hist_edges:
-                metrics["hist_staleness"] = obs_hist.fixed_histogram(
-                    slot_staleness(meta), hist_edges["hist_staleness"],
-                    weights=meta.valid)
-            if "hist_uplink_mbytes" in hist_edges:
-                metrics["hist_uplink_mbytes"] = obs_hist.fixed_histogram(
-                    mbytes_per_slot(codec, state.params, meta.valid),
-                    hist_edges["hist_uplink_mbytes"], weights=meta.valid)
-            if "hist_downlink_mbytes" in hist_edges:
-                metrics["hist_downlink_mbytes"] = obs_hist.fixed_histogram(
-                    mbytes_per_slot(down, state.params, meta.valid),
-                    hist_edges["hist_downlink_mbytes"], weights=meta.valid)
-            if "hist_suspicion" in hist_edges:
-                metrics["hist_suspicion"] = obs_hist.fixed_histogram(
-                    rb_info["suspicion"], hist_edges["hist_suspicion"],
-                    weights=meta.valid)
-            if "hist_dp_scale" in hist_edges:
-                metrics["hist_dp_scale"] = obs_hist.fixed_histogram(
-                    dp_scale, hist_edges["hist_dp_scale"],
-                    weights=meta.valid)
+            metrics.update(obs_hist.round_histograms(hist_edges, meta.valid, {
+                "hist_steps": lambda: meta.num_steps,
+                "hist_update_norm": lambda: jnp.sqrt(slot_sq),
+                "hist_staleness": lambda: slot_staleness(meta),
+                "hist_uplink_mbytes": lambda: mbytes_per_slot(codec, state.params, meta.valid),
+                "hist_downlink_mbytes": lambda: mbytes_per_slot(down, state.params, meta.valid),
+                "hist_suspicion": lambda: info["suspicion"],
+                "hist_dp_scale": lambda: info["dp_scale"],
+            }))
         return state, metrics
 
-    # the host side (train loop) pre-creates matching registry Histograms
-    # from the same static edge table the jitted emitter closed over
+    # the train loop pre-creates its registry Histograms from the same
+    # static edge table the jitted emitter closed over
     round_step.telemetry_hist_edges = hist_edges
     return round_step
 

@@ -59,7 +59,7 @@ from ..core.local import (ClientChain, build_local_step, chain_client_template,
 from ..data.federated import BucketedBatch
 from ..obs import validate_telemetry_config
 from ..utils.pytree import tree_copy, tree_zeros_like
-from .bucketing import scan_clients, vmap_clients
+from .bucketing import scan_stack, stage_cohort
 from .comm import DOWNLINK_STATE_KEY, UPLINK_STATE_KEY, build_codec
 from .fleet import (FLEET_STATE_KEY, fleet_active, fleet_client_state,
                     staleness_weights, validate_fleet_config)
@@ -314,19 +314,19 @@ def _mvr_opt() -> ServerOpt:
                 wp = meta.valid * meta.weight / meta.prob              # [C]
                 if fl.mvr_exact:
                     def grads_at(p):
-                        if isinstance(batch, BucketedBatch):
-                            # per-bucket local gradients, reassembled to [C]
-                            # slot order so the wp-weighted reduction below is
-                            # bitwise-identical to the padded layout
-                            def g(d, m):
-                                return full_local_gradient(loss_fn, p, d, m)
+                        def g(d, m):
+                            return full_local_gradient(loss_fn, p, d, m)
 
-                            if cohort_mode == "vmapped":
-                                gs = vmap_clients(g, batch)
-                                return jax.tree.map(
-                                    lambda t: jnp.einsum(
-                                        "c,c...->...", wp.astype(jnp.float32), t), gs)
-                            gs = scan_clients(g, batch)
+                        if cohort_mode == "vmapped":
+                            # [C] slot-order gradients in either layout, so
+                            # the wp-weighted reduction below is bitwise the
+                            # padded layout's
+                            gs = stage_cohort(jax.vmap(g), batch)
+                            return jax.tree.map(
+                                lambda t: jnp.einsum(
+                                    "c,c...->...", wp.astype(jnp.float32), t), gs)
+                        if isinstance(batch, BucketedBatch):
+                            gs = stage_cohort(scan_stack(g), batch)
 
                             def accum(acc, xs):
                                 G, c = xs
@@ -337,18 +337,10 @@ def _mvr_opt() -> ServerOpt:
                                 lambda x: jnp.zeros_like(x, jnp.float32), p)
                             out, _ = jax.lax.scan(accum, acc0, (gs, wp))
                             return out
-                        if cohort_mode == "vmapped":
-                            gs = jax.vmap(
-                                lambda d, m: full_local_gradient(loss_fn, p, d, m)
-                            )(batch.data, batch.step_mask)
-                            return jax.tree.map(
-                                lambda t: jnp.einsum(
-                                    "c,c...->...", wp.astype(jnp.float32), t), gs)
 
                         def body(acc, xs):
                             d, m, c = xs
-                            g = full_local_gradient(loss_fn, p, d, m)
-                            return jax.tree.map(lambda A, G: A + c * G, acc, g), None
+                            return jax.tree.map(lambda A, G: A + c * G, acc, g(d, m)), None
 
                         acc0 = jax.tree.map(
                             lambda x: jnp.zeros_like(x, jnp.float32), p)

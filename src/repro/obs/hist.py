@@ -24,10 +24,12 @@ Edge builders:
 * :func:`log_edges` — log-uniform decades for positive scale-free values
   (update norms, wire bytes).
 
-``fed.rounds`` emits (gated on ``fl.telemetry``): ``hist_steps``,
-``hist_update_norm``, plus ``hist_staleness`` when the fleet plane is on,
-``hist_uplink_mbytes`` under a non-identity codec, and ``hist_suspicion``
-(update-norm / median-norm ratios) while the robustness plane is active.
+``fed.rounds`` emits (gated on ``fl.telemetry``, through
+:func:`round_histograms`): ``hist_steps``, ``hist_update_norm``, plus
+``hist_staleness`` when the fleet plane is on, ``hist_uplink_mbytes`` /
+``hist_downlink_mbytes`` under a non-identity codec, ``hist_suspicion``
+(update-norm / median-norm ratios) while the robustness plane is active, and
+``hist_dp_scale`` under DP.
 """
 from __future__ import annotations
 
@@ -132,3 +134,12 @@ def round_hist_edges(fl, *, with_staleness: bool, with_uplink: bool,
         # how hard the clip is biting — the round's clipping profile
         edges["hist_dp_scale"] = log_edges(1e-4, 1.0, bins)
     return edges
+
+
+def round_histograms(edges: dict, valid, values: dict) -> dict:
+    """The round's ``hist_*`` metrics: for every key of ``edges`` (the
+    :func:`round_hist_edges` table, which decides what is emitted), the
+    ``[C]`` array ``values[key]()`` binned into its static edges, weighted
+    by ``valid``.  Values are thunks, so an absent key costs nothing."""
+    return {k: fixed_histogram(values[k](), e, weights=valid)
+            for k, e in edges.items()}

@@ -6,8 +6,8 @@ collectable and *running* in environments without it (e.g. hermetic CPU
 images).  It draws ``max_examples`` pseudo-random examples per test from a
 deterministic per-test seed — no shrinking, no database, just coverage.
 
-Supported subset: ``given`` (kwargs form), ``settings(max_examples, deadline)``
-and the strategies ``integers``, ``floats``, ``booleans``, ``lists``,
+Supported subset: ``given`` (kwargs form), ``settings(max_examples, deadline)``,
+``assume`` (a failed one skips the example) and the strategies ``integers``, ``floats``, ``booleans``, ``lists``,
 ``sampled_from``, ``just``, plus ``Strategy.map/filter``.
 """
 from __future__ import annotations
@@ -76,6 +76,16 @@ def settings(max_examples: int = _DEFAULT_MAX_EXAMPLES, deadline=None, **_kw):
     return deco
 
 
+class _Unsatisfied(Exception):
+    """Raised by :func:`assume`; ``given`` drops the example."""
+
+
+def assume(condition) -> bool:
+    if not condition:
+        raise _Unsatisfied
+    return True
+
+
 def given(**strategies: Strategy):
     def deco(fn):
         # NB: no functools.wraps — the wrapper must present a zero-arg
@@ -87,7 +97,10 @@ def given(**strategies: Strategy):
             rng = np.random.default_rng(seed)
             for _ in range(cfg["max_examples"]):
                 drawn = {k: s.draw(rng) for k, s in strategies.items()}
-                fn(**drawn)
+                try:
+                    fn(**drawn)
+                except _Unsatisfied:
+                    continue
 
         for attr in ("__name__", "__qualname__", "__doc__", "__module__"):
             setattr(wrapper, attr, getattr(fn, attr))

@@ -22,7 +22,7 @@ except ModuleNotFoundError:
     _spec.loader.exec_module(_fb)
     _hyp = types.ModuleType("hypothesis")
     _st = types.ModuleType("hypothesis.strategies")
-    for _name in ("given", "settings", "Strategy"):
+    for _name in ("assume", "given", "settings", "Strategy"):
         setattr(_hyp, _name, getattr(_fb, _name))
     for _name in ("integers", "floats", "booleans", "lists", "sampled_from", "just"):
         setattr(_st, _name, getattr(_fb, _name))

@@ -55,10 +55,10 @@ def test_one_federated_round(arch):
     task = TokenTask(vocab=cfg.vocab, seq_len=16, num_clients=4, extras=extras)
     pipe = FederatedPipeline(task, Population.build(fl), fl)
     params = model.init(KEY)
-    # deliberately the legacy string-dispatch entry points: init_server and
-    # build_round_step(loss_fn, fl, ...) must keep resolving via the registry
+    # deliberately the string-dispatch entry points: init_server and
+    # build_round_step(loss_fn, fl=...) must keep resolving via the registry
     state = init_server(fl, params)
-    step = jax.jit(build_round_step(make_loss(model), fl, num_clients=4))
+    step = jax.jit(build_round_step(make_loss(model), fl=fl, num_clients=4))
     state, mets = step(state, as_device_batch(pipe.round_batch(0)))
     assert bool(jnp.isfinite(mets["local_loss"]))
     assert float(mets["delta_norm"]) > 0

@@ -10,7 +10,7 @@ primitives.
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import assume, given, settings
 from hypothesis import strategies as st
 
 from repro.configs.base import FLConfig
@@ -178,7 +178,11 @@ def test_trimmed_mean_recovers_honest_value_below_trim(v, bad, n, trim,
                                                        low_side):
     """Adversarial coefficient mass strictly below trim_frac * W lands
     entirely outside the central window — the estimate is exactly v * W."""
-    n_adv = max(1, int(trim * n) - 1)                    # mass < trim * W
+    # the aggregator's window [lo, hi] in its own float32 arithmetic; the
+    # most adversaries whose mass stays strictly below lo, and at least one
+    lo, hi = np.float32(trim) * np.float32(n), np.float32(1.0 - trim) * np.float32(n)
+    n_adv = int(np.ceil(lo)) - 1
+    assume(n_adv >= 1 and n - n_adv > hi)
     vals = [v] * (n - n_adv) + [(-bad if low_side else bad)] * n_adv
     coeff = np.ones(n, np.float32)
     out = _agg("trimmed_mean", _stack(vals), coeff, _meta(np.ones(n)),

@@ -285,8 +285,10 @@ def test_mvr_exact_matches_seed(mode):
 
 
 def test_legacy_signature_matches_new_api():
-    """build_round_step(loss_fn, fl, num_clients=...) — the deprecation shim —
-    must produce the exact same trajectory as the explicit-strategy call."""
+    """build_round_step(loss_fn, fl=..., num_clients=...), the FLConfig's
+    strings resolved through the registry, must produce the exact same
+    trajectory as the explicit-strategy call; the retired form with the
+    FLConfig in the strategy's place fails loudly."""
     fl = _fl("fedshuffle", "vmapped", "momentum")
     pipe = FederatedPipeline(TASK, Population.build(fl, sizes=TASK.sizes()), fl)
     batch = as_device_batch(pipe.round_batch(0))
@@ -295,14 +297,16 @@ def test_legacy_signature_matches_new_api():
     strategy = bind_strategy(strategy_for(fl), fl, LOSS, num_clients=3)
     s_new, m_new = build_round_step(LOSS, strategy, fl, num_clients=3)(
         strategy.init(params), batch)
-    s_old, m_old = build_round_step(LOSS, fl, num_clients=3)(
+    s_old, m_old = build_round_step(LOSS, fl=fl, num_clients=3)(
         strategy.init(params), batch)
-    _assert_tree_equal(s_new.params, s_old.params, "legacy shim params")
-    _assert_tree_equal(s_new.opt, s_old.opt, "legacy shim opt")
-    _assert_tree_equal(m_new, m_old, "legacy shim metrics")
+    _assert_tree_equal(s_new.params, s_old.params, "registry params")
+    _assert_tree_equal(s_new.opt, s_old.opt, "registry opt")
+    _assert_tree_equal(m_new, m_old, "registry metrics")
 
-    # positional num_clients (the original signature) must also resolve
-    s_pos, _ = build_round_step(LOSS, fl, 3)(strategy.init(params), batch)
-    _assert_tree_equal(s_new.params, s_pos.params, "legacy positional params")
+    # positional num_clients must also resolve
+    s_pos, _ = build_round_step(LOSS, None, fl, 3)(strategy.init(params), batch)
+    _assert_tree_equal(s_new.params, s_pos.params, "positional params")
+    with pytest.raises(TypeError):
+        build_round_step(LOSS, fl, num_clients=3)
     with pytest.raises(TypeError):
         build_round_step(LOSS, fl, fl)
